@@ -36,9 +36,10 @@ AND ACCOUNTED, never silent.  Three legs feed one verdict,
 
 On CPU hosts the pallas legs run in interpret mode and the 8 "devices"
 share one machine — regress on the agreement verdict, never on CPU
-timings.  Needs a multi-device jax, so ``run()`` re-executes this module
-in a child process with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-(the driver imports suites after jax locks its device count).
+timings.  Needs 8 devices (``common.run_multi_device``): it runs in this
+process where JAX already sees 8; a CPU host re-executes this module in a
+child with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; an
+accelerator host with fewer chips refuses (one process per chip).
 
 Results land in ``results/bench.json`` AND merge into
 ``BENCH_serving.json`` as the ``chaos`` section.
@@ -48,8 +49,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
-import subprocess
 import sys
 from typing import Dict
 
@@ -62,7 +61,7 @@ SHED_CELLS = (("xla", "scalar"), ("pallas", "scalar"), ("pallas", "dma"))
 
 
 def _child_run(seed: int) -> Dict:
-    """Runs inside the 8-device child process."""
+    """The suite body, on 8 devices."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -73,7 +72,7 @@ def _child_run(seed: int) -> Dict:
     from repro.graphs.synthetic import (
         SyntheticGraphConfig, generate, small_test_graph, top_degree_pins,
     )
-    from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.serving.resilience import ResilienceConfig, overlap_at_k
     from repro.serving.server import PixieServer
     from repro.serving.traffic import (
@@ -205,8 +204,8 @@ def _child_run(seed: int) -> Dict:
         n_steps=2_048, n_walkers=32, chunk_steps=4, top_k=20,
         n_p=30, n_v=3, bias_beta=0.0, count_boards=True,
     )
-    mesh = make_mesh_compat((N_SHARDS,), ("model",))
-    shg = dist_lib.shard_graph(tg, N_SHARDS)
+    mesh = make_mesh((N_SHARDS,), ("model",))
+    shg = dist_lib.shard_graph(tg, N_SHARDS, mesh)
     batch, n_slots = 4, 4
     pins = np.full((batch, n_slots), -1, np.int32)
     weights = np.zeros((batch, n_slots), np.float32)
@@ -221,7 +220,7 @@ def _child_run(seed: int) -> Dict:
     dead_sched = np.full((N_SHARDS,), never, np.int32)
     dead_sched[victim] = death_step
 
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         def engine(dead_at):
             return dist_lib.pixie_walk_sharded_batched(
                 shg, pins_j, weights_j, keys, dcfg, mesh, slack=16.0,
@@ -339,28 +338,12 @@ def _child_run(seed: int) -> Dict:
 
 
 def run(seed: int = 0) -> Dict:
-    """Driver entry: re-exec in a child with 8 forced host devices."""
-    from benchmarks.common import merge_serving_section
+    """Driver entry: the chaos legs on 8 devices (``run_multi_device``)."""
+    from benchmarks.common import merge_serving_section, run_multi_device
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={N_DEVICES}"
-    ).strip()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo, env.get("PYTHONPATH", "")]
+    ch: Dict = run_multi_device(
+        "benchmarks.bench_chaos", N_DEVICES, seed, _child_run
     )
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_chaos", "--child",
-         "--seed", str(seed)],
-        capture_output=True, text=True, env=env, cwd=repo, timeout=3600,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"bench_chaos child failed:\n{proc.stderr[-3000:]}"
-        )
-    ch: Dict = json.loads(proc.stdout.strip().splitlines()[-1])
     out: Dict = {"chaos": ch}
     # verdict: (1) shed-budget chaos results bit-identical to an unloaded
     # oracle dispatched with the same shrunk budgets, across backend x

@@ -20,9 +20,10 @@ zero drops at parity slack.  On CPU hosts the kernels run in interpret
 mode and the 8 "devices" share one machine — ms columns measure plumbing,
 not ICI; regress on ``sharded_engine_agrees``, not the CPU ratios.
 
-Needs a multi-device jax, but the driver imports suites after jax locks
-its device count — so ``run()`` re-executes this module in a child
-process with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+Needs 8 devices (``common.run_multi_device``): it runs in this process
+where JAX already sees 8; a CPU host re-executes this module in a child
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; an
+accelerator host with fewer chips refuses (one process per chip).
 
 Results land in ``results/bench.json`` AND merge into
 ``BENCH_serving.json`` as the ``sharded`` section.
@@ -32,8 +33,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
-import subprocess
 import sys
 import time
 from typing import Dict
@@ -46,7 +45,7 @@ N_SLOTS = 4
 
 
 def _child_sweep(seed: int) -> Dict:
-    """Runs inside the 8-device child process."""
+    """The suite body, on 8 devices."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -55,7 +54,7 @@ def _child_sweep(seed: int) -> Dict:
     from repro.core import distributed as dist_lib
     from repro.core import walk as walk_lib
     from repro.graphs.synthetic import small_test_graph, top_degree_pins
-    from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     sg = small_test_graph(seed)
     g = sg.graph
@@ -98,8 +97,8 @@ def _child_sweep(seed: int) -> Dict:
     agree_all = True
     supersteps = base.max_chunks() * base.chunk_steps
     for n_shards in SHARDS:
-        mesh = make_mesh_compat((n_shards,), ("model",))
-        shg = dist_lib.shard_graph(g, n_shards)
+        mesh = make_mesh((n_shards,), ("model",))
+        shg = dist_lib.shard_graph(g, n_shards, mesh)
         for batch in BATCHES:
             pins, weights = queries(batch)
             keys = jax.random.split(jax.random.key(seed), batch)
@@ -114,7 +113,7 @@ def _child_sweep(seed: int) -> Dict:
             if n_shards in (2, 4):
                 engines.append(("fused_dma", "dma"))
             row_ok = True
-            with set_mesh_compat(mesh):
+            with jax.set_mesh(mesh):
                 for label, gather in engines:
                     cfg = dataclasses.replace(
                         base,
@@ -156,11 +155,11 @@ def _child_sweep(seed: int) -> Dict:
 
     # starved-slack illustration: drops are COUNTED, not silent (no parity
     # claim here — dropped walkers are bounded Monte Carlo slack)
-    mesh = make_mesh_compat((2,), ("model",))
-    shg = dist_lib.shard_graph(g, 2)
+    mesh = make_mesh((2,), ("model",))
+    shg = dist_lib.shard_graph(g, 2, mesh)
     pins, weights = queries(8)
     keys = jax.random.split(jax.random.key(seed), 8)
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         res = jax.block_until_ready(
             dist_lib.pixie_walk_sharded_batched(
                 shg, pins, weights, keys, base, mesh, slack=0.05
@@ -191,28 +190,12 @@ def _child_sweep(seed: int) -> Dict:
 
 
 def run(seed: int = 0) -> Dict:
-    """Driver entry: re-exec in a child with 8 forced host devices."""
-    from benchmarks.common import merge_serving_section
+    """Driver entry: the sweep on 8 devices (``run_multi_device``)."""
+    from benchmarks.common import merge_serving_section, run_multi_device
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={N_DEVICES}"
-    ).strip()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(repo, "src"), repo, env.get("PYTHONPATH", "")]
-    )
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sharded", "--child",
-         "--seed", str(seed)],
-        capture_output=True, text=True, env=env, cwd=repo, timeout=3600,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"bench_sharded child failed:\n{proc.stderr[-3000:]}"
-        )
-    out: Dict = {"sharded": json.loads(proc.stdout.strip().splitlines()[-1])}
+    out: Dict = {"sharded": run_multi_device(
+        "benchmarks.bench_sharded", N_DEVICES, seed, _child_sweep
+    )}
     # verdict: fused sharded == xla sharded == unsharded batched engine,
     # bit-identically (counts, board counts, steps_taken, n_high), zero
     # drops at parity slack, for every (n_shards, batch) cell — and

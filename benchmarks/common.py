@@ -6,8 +6,10 @@ from __future__ import annotations
 import functools
 import json
 import os
+import subprocess
+import sys
 import time
-from typing import Dict
+from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +48,44 @@ def merge_serving_section(name: str, payload: Dict) -> str:
     with open(BENCH_SERVING_PATH, "w") as f:
         json.dump(data, f, indent=2)
     return BENCH_SERVING_PATH
+
+
+def run_multi_device(
+    module: str, n_devices: int, seed: int, body: Callable[[int], Dict]
+) -> Dict:
+    """``body(seed)`` on ``n_devices`` devices, one process per chip.
+
+    Where this process already sees ``n_devices`` devices, the body runs
+    here.  A CPU host with fewer re-executes ``python -m <module> --child
+    --seed <seed>`` with that many forced host devices and returns the
+    child's last JSON line.  An accelerator host with fewer refuses: this
+    process now holds its chips, so a child could not reach them.
+    """
+    if len(jax.devices()) >= n_devices:
+        return body(seed)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{module} needs {n_devices} devices; this "
+            f"{jax.default_backend()} host has {len(jax.devices())}, and a "
+            "child process cannot share the chips this one holds"
+        )
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={n_devices}"
+    ).strip()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(repo, "src"), repo, env.get("PYTHONPATH", "")]
+    )
+    proc = subprocess.run(
+        [sys.executable, "-m", module, "--child", "--seed", str(seed)],
+        capture_output=True, text=True, env=env, cwd=repo, timeout=3600,
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(f"{module} child failed:\n{proc.stderr[-3000:]}")
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 @functools.lru_cache(maxsize=2)

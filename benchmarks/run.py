@@ -34,6 +34,7 @@ from benchmarks import (
     bench_two_stage,
     bench_widepack,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "table1": ("Table 1: hit-rate vs content baselines",
@@ -101,6 +102,8 @@ def main(argv=None):
                     "subprocess per suite — XLA CPU JIT memory accumulates "
                     "across suites otherwise)")
     args = ap.parse_args(argv)
+    # sets a config value only: the parent still starts no backend
+    enable_compile_cache()
 
     names = args.only or list(SUITES)
 

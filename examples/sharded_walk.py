@@ -20,7 +20,7 @@ import numpy as np
 from repro.core import distributed as D
 from repro.core import walk as W
 from repro.graphs.synthetic import SyntheticGraphConfig, generate
-from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+from repro.launch.mesh import make_mesh
 
 def main(
     n_pins: int = 8_000,
@@ -37,8 +37,8 @@ def main(
     through this same path).  Returns (overlap, dropped)."""
     sg = generate(SyntheticGraphConfig(n_pins=n_pins, n_boards=n_boards,
                                        seed=3))
-    mesh = make_mesh_compat(mesh_shape, ("data", "model")[-len(mesh_shape):])
-    shg = D.shard_graph(sg.graph, n_shards)
+    mesh = make_mesh(mesh_shape, ("data", "model")[-len(mesh_shape):])
+    shg = D.shard_graph(sg.graph, n_shards, mesh)
     print(f"graph sharded {n_shards} ways: {shg.pins_per_shard} pins/shard, "
           f"{shg.boards_per_shard} boards/shard")
 
@@ -51,7 +51,7 @@ def main(
         n_supersteps=n_supersteps, walkers_per_shard=walkers_per_shard,
         top_k=top_k, slack=slack,
     )
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         res = D.pixie_walk_sharded(shg, qp, qw, jax.random.key(0), cfg, mesh)
     print(f"walkers dropped by routing capacity: {int(res.dropped)}")
     print("top pins (pod-sharded batched fused walk):")

@@ -61,8 +61,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import counter as counter_lib
 from repro.core import sampling
@@ -102,8 +101,18 @@ class ShardedGraph(NamedTuple):
         return self.b2p_offsets.shape[1] - 1
 
 
-def shard_graph(graph: PinBoardGraph, n_shards: int) -> ShardedGraph:
-    """Split a host graph into node-range shards (padded to equal size)."""
+def shard_graph(
+    graph: PinBoardGraph,
+    n_shards: int,
+    mesh: Optional[Mesh] = None,
+    axis: str = "model",
+) -> ShardedGraph:
+    """Split a host graph into node-range shards (padded to equal size).
+
+    With ``mesh``, each shard row is placed straight onto the device that
+    owns it along ``axis``, so no device ever holds the whole stacked
+    CSR; without it the arrays land on the default device.
+    """
     n_pins = -(-graph.n_pins // n_shards) * n_shards
     n_boards = -(-graph.n_boards // n_shards) * n_shards
     pps, bps = n_pins // n_shards, n_boards // n_shards
@@ -133,11 +142,18 @@ def shard_graph(graph: PinBoardGraph, n_shards: int) -> ShardedGraph:
     max_bt = max(len(t) for t in bt)
     pt = [np.pad(t, (0, max_pt - len(t))) for t in pt]
     bt = [np.pad(t, (0, max_bt - len(t))) for t in bt]
+
+    rows = None if mesh is None else NamedSharding(mesh, P(axis, None))
+
+    def stacked(parts):
+        x = np.stack(parts).astype(np.int32)
+        return jnp.asarray(x) if rows is None else jax.device_put(x, rows)
+
     return ShardedGraph(
-        p2b_offsets=jnp.asarray(np.stack(po).astype(np.int32)),
-        p2b_targets=jnp.asarray(np.stack(pt).astype(np.int32)),
-        b2p_offsets=jnp.asarray(np.stack(bo).astype(np.int32)),
-        b2p_targets=jnp.asarray(np.stack(bt).astype(np.int32)),
+        p2b_offsets=stacked(po),
+        p2b_targets=stacked(pt),
+        b2p_offsets=stacked(bo),
+        b2p_targets=stacked(bt),
         n_pins=n_pins,
         n_boards=n_boards,
         n_shards=n_shards,
@@ -696,7 +712,7 @@ def pixie_walk_sharded_batched(
 
     shd = P(axis, None)
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         local_walk,
         mesh=mesh,
         in_specs=(shd, shd, shd, shd, rep, rep, rep, rep)
@@ -704,7 +720,7 @@ def pixie_walk_sharded_batched(
         out_specs=(
             shd, shd if cfg.count_boards else None, rep, rep, rep, rep
         ) + ((rep,) if faulty else ()),
-        check_rep=False,
+        check_vma=False,
     )
     args = (
         graph.p2b_offsets, graph.p2b_targets,

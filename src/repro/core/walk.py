@@ -17,15 +17,15 @@ Two interchangeable step engines (``WalkConfig.backend``):
   * ``"pallas"`` — the fused multi-superstep Pallas kernel
                    (kernels/walk_step.walk_steps_fused): ONE kernel launch
                    per ``chunk_steps`` steps with walker state resident in
-                   VMEM across the whole chunk, wide (slot, pin) visit
+                   SMEM across the whole chunk, wide (slot, pin) visit
                    events emitted in-kernel, and counts recovered with the
                    scatter-free tile-scan ``visit_counter`` kernels.  Its
-                   CSR gathers come in two bit-identical flavours
-                   (``WalkConfig.gather_mode``): blocking per-walker
-                   scalar loads ("scalar") or the phase-split
-                   double-buffered async-DMA prefetch ("dma") that hides
-                   each walker's HBM latency behind its neighbour's.  On
-                   CPU hosts the kernel runs in interpret mode.
+                   CSR reads are row DMAs in two bit-identical orders
+                   (``WalkConfig.gather_mode``): each walker's copies
+                   waited on at once ("scalar"), or double-buffered so
+                   each walker's HBM latency hides behind its neighbour's
+                   ("dma").  On CPU hosts the kernel runs in interpret
+                   mode.
 
 Events are WIDE — two int32 lanes, (slot, pin), slot lane ``n_slots`` as
 the invalid-step sentinel — never the packed ``slot * n_pins + pin``
@@ -194,13 +194,16 @@ class WalkConfig:
                   or "pallas" (fused multi-superstep kernel + tile-scan
                   histogram counts).  Both produce bit-identical visits.
     pallas_block_w: walkers per Pallas grid cell (None = auto).
-    gather_mode:  how the pallas engine issues its per-walker CSR gathers:
-                  "scalar" (blocking scalar loads) or "dma" (phase-split
-                  double-buffered async-copy prefetch — hides the HBM
-                  latency of walker i's rows behind walker i+1's).  Bit-
-                  identical to "scalar" and to the xla engine; a pure
-                  memory-latency knob on TPU hosts (interpret-mode CPU
-                  timings don't show it).  Ignored by backend="xla".
+    gather_mode:  how the pallas engine issues its per-walker CSR reads,
+                  each a copy of one 128-element HBM row into SMEM:
+                  "scalar" (walker by walker, each copy waited on as soon
+                  as it is started — one exposed HBM round trip per hop
+                  phase per walker) or "dma" (phase by phase over the
+                  block, walker i+1's copies started before walker i's
+                  are waited on — one latency hides behind the next).
+                  Bit-identical to each other and to the xla engine; a
+                  pure memory-latency knob on TPU hosts (interpret-mode
+                  CPU timings don't show it).  Ignored by backend="xla".
     """
 
     n_steps: int = 100_000

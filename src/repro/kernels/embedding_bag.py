@@ -1,12 +1,13 @@
 """Pallas TPU kernel: EmbeddingBag (gather + pool) for the recsys substrate.
 
 JAX has no native EmbeddingBag; the oracle is `take + segment-style pooling`
-(ref.py).  The kernel tiles the *batch* of bags into VMEM, leaves the
-embedding table in HBM (memory_space=ANY — recsys tables are 10^6..10^9
-rows and never fit VMEM), and gathers + accumulates rows per bag with the
-feature dimension vectorized across lanes.  This is the v5e analogue of the
-SparseCore lookup: ids are small VMEM-resident integers, each id costs one
-HBM row fetch of d*4 bytes, pooling is free (accumulated in VREGs).
+(ref.py).  The kernel tiles the *batch* of bags across the grid, keeps
+each block's ids and weights in SMEM, leaves the embedding table in HBM
+(memory_space=ANY — recsys tables are 10^6..10^9 rows and never fit VMEM),
+and DMAs each id's row into VMEM, pooling with the feature dimension
+vectorized across lanes.  This is the v5e analogue of the SparseCore
+lookup: each id costs one HBM row copy of d*4 bytes, pooling is free
+(accumulated in VREGs).
 
 Fixed bag size with -1 padding keeps every shape static (SPMD-friendly);
 multi-hot recsys features and DLRM single-hot lookups (bag size 1) are both
@@ -21,38 +22,65 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_B = 64  # bags per grid cell
+_LANES = 128
 
 
 def _embedding_bag_kernel(
-    ids_ref, weights_ref, table_ref, out_ref, *, block_b: int, bag: int,
-    mean: bool,
+    ids_ref, weights_ref, table_ref, out_ref, rows, sem,
+    *, block_b: int, bag: int, mean: bool,
 ):
+    """Pool ``block_b`` bags; ids and weights are SMEM scalars, table rows
+    arrive by DMA from HBM into VMEM row slots.
+
+    Bag b+1's row copies are started before bag b's are waited on (two
+    slot sets of ``bag`` rows), so one bag's HBM latency hides behind the
+    previous bag's pooling.  Pooling runs in ascending element order.
+    """
     d = out_ref.shape[-1]
 
-    def bag_body(b, acc):
-        def elem_body(l, inner):
-            acc, wsum = inner
-            idx = ids_ref[b, l]
-            valid = idx >= 0
-            safe = jnp.where(valid, idx, 0)
-            row = table_ref[pl.ds(safe, 1), :]  # (1, d)
-            w = weights_ref[b, l] * valid.astype(jnp.float32)
-            acc = acc + row[0].astype(jnp.float32) * w
-            return acc, wsum + w
+    def row_id(b, l):
+        return jnp.maximum(ids_ref[b, l], 0)
 
-        acc_b, wsum = jax.lax.fori_loop(
-            0, bag, elem_body, (jnp.zeros((d,), jnp.float32), 0.0)
+    def copy(b, l, slot):
+        return pltpu.make_async_copy(
+            table_ref.at[pl.ds(row_id(b, l), 1)],
+            rows.at[slot * bag + l],
+            sem.at[slot],
         )
-        if mean:
-            acc_b = acc_b / jnp.maximum(wsum, 1.0)
-        return acc.at[b].set(acc_b)
 
-    out = jax.lax.fori_loop(
-        0, block_b, bag_body, jnp.zeros((block_b, d), jnp.float32)
-    )
-    out_ref[...] = out.astype(out_ref.dtype)
+    def start(b, slot):
+        for l in range(bag):
+            copy(b, l, slot).start()
+
+    start(0, 0)
+
+    def bag_body(b, carry):
+        slot = b % 2
+
+        @pl.when(b + 1 < block_b)
+        def _prefetch():
+            start(b + 1, 1 - slot)
+
+        # the bag's copies share one semaphore, so a single wait does not
+        # say which row landed: wait for all of them before reading any
+        for l in range(bag):
+            copy(b, l, slot).wait()
+        acc = jnp.zeros((1, d), jnp.float32)
+        wsum = jnp.zeros((1, d), jnp.float32)
+        for l in range(bag):
+            valid = (ids_ref[b, l] >= 0).astype(jnp.float32)
+            w = jnp.full((1, d), weights_ref[b, l] * valid, jnp.float32)
+            acc = acc + rows[slot * bag + l].astype(jnp.float32) * w
+            wsum = wsum + w
+        if mean:
+            acc = acc / jnp.maximum(wsum, 1.0)
+        out_ref[pl.ds(b, 1), :] = acc.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, block_b, bag_body, 0)
 
 
 def _bag_pallas_call(
@@ -73,6 +101,11 @@ def _bag_pallas_call(
     """
     n, bag = ids2.shape
     v, d = table.shape
+    # a table row is DMA'd whole, and the DMA engine moves 128-lane rows:
+    # pad the feature dim (zero lanes pool to zero and are sliced off)
+    d_pad = -(-d // _LANES) * _LANES
+    if d_pad != d:
+        table = jnp.pad(table, ((0, 0), (0, d_pad - d)))
     n_pad = -(-n // block_b) * block_b
     if n_pad != n:
         ids2 = jnp.concatenate(
@@ -81,7 +114,7 @@ def _bag_pallas_call(
         weights2 = jnp.concatenate(
             [weights2, jnp.zeros((n_pad - n, bag), weights2.dtype)]
         )
-    grid = (n_pad // block_b,)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         functools.partial(
             _embedding_bag_kernel,
@@ -89,17 +122,21 @@ def _bag_pallas_call(
             bag=bag,
             mean=(mode == "mean"),
         ),
-        grid=grid,
+        grid=(n_pad // block_b,),
         in_specs=[
-            pl.BlockSpec((block_b, bag), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, bag), lambda i: (i, 0)),
+            smem((block_b, bag), lambda i: (i, 0)),
+            smem((block_b, bag), lambda i: (i, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), table.dtype),
+        out_specs=pl.BlockSpec((block_b, d_pad), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), table.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((2 * bag, 1, d_pad), table.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
         interpret=interpret,
     )(ids2.astype(jnp.int32), weights2.astype(jnp.float32), table)
-    return out[:n]
+    return out[:n, :d]
 
 
 @functools.partial(

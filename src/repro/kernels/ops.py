@@ -177,14 +177,14 @@ def walk_chunk_fused(
     invalid steps; the board lane shares the slot lane), so both engines
     cover packed id spaces past 2**31 with no fallback.  The kernel path
     runs ALL chunk_steps steps in one pallas_call with walker state
-    resident in VMEM; the oracle path is the same arithmetic as two-level
+    resident in SMEM; the oracle path is the same arithmetic as two-level
     XLA gathers (this is the walk's "xla" backend).  Both consume the same
     (chunk_steps, w, 4) uint32 counter-RNG bits, so their emitted events
     agree bit-for-bit.
 
-    ``gather_mode`` ("scalar" | "dma") selects how the kernel path issues
-    its CSR gathers — blocking scalar loads or the double-buffered
-    async-copy pipeline; both are bit-identical to the oracle.  The oracle
+    ``gather_mode`` ("scalar" | "dma") selects how the kernel path orders
+    its CSR row copies — each waited on at once, or double-buffered
+    across walkers; both are bit-identical to the oracle.  The oracle
     path has no gather modes (XLA vector gathers) and ignores it.
     """
     if use_kernel is None:
@@ -199,7 +199,7 @@ def walk_chunk_fused(
             curr, query, feat, slot, rbits,
             p2b_offsets, p2b_targets, b2p_offsets, b2p_targets,
             p2b_feat_bounds, b2p_feat_bounds,
-            n_pins=n_pins, n_slots=n_slots, n_boards=n_boards,
+            n_pins=n_pins, n_slots=n_slots,
             alpha_u32=alpha_u32, beta_u32=beta_u32,
             count_boards=count_boards, block_w=block_w,
             gather_mode=gather_mode,
@@ -263,7 +263,7 @@ def walk_chunk_fused_batched(
             curr, query, feat, slot, rbits,
             p2b_offsets, p2b_targets, b2p_offsets, b2p_targets,
             p2b_feat_bounds, b2p_feat_bounds, qid,
-            n_pins=n_pins, n_slots=n_slots, n_boards=n_boards,
+            n_pins=n_pins, n_slots=n_slots,
             n_queries=n_queries,
             alpha_u32=alpha_u32, beta_u32=beta_u32,
             count_boards=count_boards, block_w=block_w,

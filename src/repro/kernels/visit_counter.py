@@ -15,8 +15,8 @@ scatters anywhere.  Grid = (n_tiles, n_chunks); the chunk axis is innermost
 so each tile block accumulates across event chunks in place.
 
 VMEM budget per program: tile (TILE,) int32 + chunk (CHUNK,) int32 + the
-(CHUNK, TILE) one-hot intermediate = 4*(512 + 2048 + 512*2048) B ~ 4.2 MiB,
-comfortably inside the ~16 MiB v5e VMEM.
+(CHUNK, TILE) one-hot intermediate = 4*(1024 + 2048 + 1024*2048) B ~ 8 MiB,
+inside the 16 MiB of VMEM a v5e kernel may claim by default.
 
 Three entry points share the tile-scan core:
 
@@ -57,9 +57,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_TILE = 512     # count-table entries per grid cell (lane-dim multiple)
+# 1-D int32 arrays sit in HBM in 1024-element tiles, and a kernel block of
+# a 1-D operand must be a whole number of them: both sizes stay multiples
+DEFAULT_TILE = 1024    # count-table entries per grid cell
 DEFAULT_CHUNK = 2048   # events streamed per inner grid step
-SLOT_PAD = 8           # sublane-friendly padding of the per-slot high output
+SLOT_PAD = 8           # padding of the per-row crossing tally (lane dim)
 
 
 def _visit_counter_kernel(events_ref, counts_ref, *, tile: int, chunk: int):
@@ -275,8 +277,10 @@ def _visit_counter_high_kernel(
     compared against its prior values: entries that crossed
     ``count >= n_v`` during this update are summed per count row
     (``bin // n_pins`` — the query slot, or the (query, slot) pair in
-    batch mode) with a one-hot compare — no scatter, no full-buffer
-    reduction outside the kernel.
+    batch mode) by a one-hot matmul — no scatter, no full-buffer
+    reduction outside the kernel.  The ``(1, slot_pad)`` tally block maps
+    to the same place at every grid step, so it stays resident and
+    accumulates across all tiles.
     """
     if n_queries:
         q_ref, slot_ref, pin_ref, prior_ref, counts_ref, high_ref = refs
@@ -286,10 +290,13 @@ def _visit_counter_high_kernel(
     j = pl.program_id(1)
     tile_base = pl.program_id(0) * tile
 
+    @pl.when((pl.program_id(0) == 0) & (j == 0))
+    def _init_high():
+        high_ref[...] = jnp.zeros_like(high_ref)
+
     @pl.when(j == 0)
     def _init():
         counts_ref[...] = prior_ref[...]
-        high_ref[...] = jnp.zeros_like(high_ref)
 
     ev = _flat_ids_from_lanes(
         slot_ref[...], pin_ref[...], n_slots, n_pins,
@@ -306,18 +313,17 @@ def _visit_counter_high_kernel(
         new = counts_ref[...]
         # n_v is compared, never added: a huge disable-early-stop sentinel
         # (e.g. int32max // 2) cannot overflow anything here.
-        crossed = ((prior < n_v) & (new >= n_v)).astype(jnp.int32)
-        bin_row = tile_base + jax.lax.broadcasted_iota(
-            jnp.int32, (1, tile), 1
-        )                                                  # (1, tile)
-        slot_row = bin_row // n_pins
-        slot_col = jax.lax.broadcasted_iota(
-            jnp.int32, (slot_pad, tile), 0
+        crossed = ((prior < n_v) & (new >= n_v)).astype(jnp.float32)
+        bin_col = tile_base + jax.lax.broadcasted_iota(
+            jnp.int32, (tile, slot_pad), 0
         )
-        onehot = (slot_col == slot_row).astype(jnp.int32)  # (slot_pad, tile)
-        high_ref[...] = jnp.sum(
-            onehot * crossed[None, :], axis=1
-        )[None, :]
+        slot_ids = jax.lax.broadcasted_iota(jnp.int32, (tile, slot_pad), 1)
+        onehot = (bin_col // n_pins == slot_ids).astype(jnp.float32)
+        # 0/1 operands and sums <= tile < 2**24: the f32 matmul is exact
+        per_row = jnp.dot(
+            crossed[None, :], onehot, preferred_element_type=jnp.float32
+        )                                                  # (1, slot_pad)
+        high_ref[...] += per_row.astype(jnp.int32)
 
 
 @functools.partial(
@@ -390,7 +396,7 @@ def visit_counter_update_high(
     slot_pad = -(-n_rows // SLOT_PAD) * SLOT_PAD
     n_tiles, n_chunks = n_pad // tile, m_pad // chunk
     ev_spec = pl.BlockSpec((chunk,), lambda i, j: (j,))
-    counts, high_parts = pl.pallas_call(
+    counts, high = pl.pallas_call(
         functools.partial(
             _visit_counter_high_kernel,
             tile=tile, chunk=chunk, n_chunks=n_chunks,
@@ -403,13 +409,13 @@ def visit_counter_update_high(
         ],
         out_specs=[
             pl.BlockSpec((tile,), lambda i, j: (i,)),
-            pl.BlockSpec((1, slot_pad), lambda i, j: (i, 0)),
+            # one resident tally row for the whole grid
+            pl.BlockSpec((1, slot_pad), lambda i, j: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles, slot_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, slot_pad), jnp.int32),
         ],
         interpret=interpret,
     )(*lanes, prior)
-    # (n_tiles, slot_pad) partials: a tiny reduction, NOT O(n_rows*n_pins)
-    return counts[:n_bins], jnp.sum(high_parts, axis=0)[:n_rows]
+    return counts[:n_bins], high[0, :n_rows]

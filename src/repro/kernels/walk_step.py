@@ -8,7 +8,7 @@ Two generations of kernel live here:
 * ``walk_steps_fused``— the serving-path engine: ONE ``pallas_call`` executes
                         ``chunk_steps`` supersteps.  Walker state (``curr``,
                         per-walker restart pin, per-walker personalization
-                        feature, query-slot id) is loaded into VMEM once and
+                        feature, query-slot id) is loaded into SMEM once and
                         stays resident across every step of the chunk; only
                         the unavoidable CSR gathers touch HBM.  Each step the
                         kernel also *emits* wide (slot, pin) visit events —
@@ -27,34 +27,36 @@ The paper's inner loop (Algorithm 2 lines 6-13) is three dependent random
 memory accesses per step: offsets[pin] -> targets[...] (board), then
 offsets[board] -> targets[...] (pin).  On TPU the CSR arrays live in HBM
 (memory_space=ANY — gigabytes, never blockable into VMEM); the fused kernel
-keeps everything *else* out of HBM: random bits are blocked into VMEM with
-the walker state, all decision logic (restart select, bias select, modulo,
-event packing) is vectorized across the walker block, and only the
-per-walker two-level CSR gathers touch HBM (they are data-dependent random
-access — there is no vector shape for them).  The paper's "walk never
-leaves the machine" becomes "walker state never leaves VMEM between
-supersteps; one kernel launch per *chunk*, not per step".
+keeps everything *else* out of HBM: the chunk's random bits, the walker
+state and the event blocks sit in SMEM, where the scalar unit reads and
+writes them at walker granularity, and only the per-walker two-level CSR
+reads touch HBM (they are data-dependent random access — there is no
+vector shape for them).  The paper's "walk never leaves the machine"
+becomes "walker state never leaves on-chip memory between supersteps; one
+kernel launch per *chunk*, not per step".
 
-Those unavoidable CSR gathers come in two flavours (``gather_mode``):
+A TPU kernel cannot load from an HBM ref, and a loaded vector cannot be
+indexed by a loop counter, so every CSR read is a DMA: the arrays are
+viewed as ``(rows, 128)`` int32 and the row holding the wanted element is
+copied into an SMEM row slot, from which the scalar unit picks the
+element.  A superstep is four gather *phases* — hop-1 offset rows (plus
+the bias-bound rows), hop-1 target, hop-2 offset (plus bias bounds), hop-2
+target — each ending in the scalar decision arithmetic that yields the
+next phase's addresses.  ``gather_mode`` only orders the copies:
 
-* ``"scalar"`` — each walker's rows are loaded with blocking scalar reads
-  inside the per-walker loop (the original formulation; every load eats a
-  full HBM round trip back to back).
-* ``"dma"``    — each superstep is split into hop *phases* (offset rows,
-  then target rows; bias-bound rows ride the offset phase).  Within a
-  phase the per-walker rows are staged into VMEM scratch by a
-  double-buffered ``pltpu.make_async_copy`` pipeline: walker *i+1*'s row
-  copy is started before walker *i*'s is waited on, so one HBM latency
-  hides behind the neighbouring walker's and the phase's decision
-  arithmetic runs vectorized over the whole block once the rows are
-  resident.  Scratch rows + DMA semaphores are allocated with
-  ``pl.run_scoped``; the same code path runs under interpret mode on CPU
-  hosts (the interpreter executes the copies synchronously), so CI
-  exercises the dma kernel bit-for-bit.
+* ``"scalar"`` — walker by walker: each phase's row copies (two to four)
+  are started and waited on at once, so every phase of every walker
+  exposes one full HBM round trip.
+* ``"dma"``    — phase by phase over the block, double-buffered: walker
+  *i+1*'s copies are started before walker *i*'s are waited on, so one
+  HBM latency hides behind the neighbouring walker's.
 
-Both gather modes do identical integer arithmetic on identical random bits
-and are bit-for-bit interchangeable (tests/test_dma_gather.py); the mode is
-purely a memory-latency knob for real TPU hosts.
+The same code runs under interpret mode on CPU hosts (the interpreter
+executes the copies synchronously), so CI exercises both modes
+bit-for-bit.  They do identical integer arithmetic on identical random
+bits and are bit-for-bit interchangeable with each other and with the XLA
+reference (tests/test_dma_gather.py); the mode is purely a memory-latency
+knob for real TPU hosts.
 
 Random bits are generated *outside* (counter-based threefry, one uint32
 quadruple per walker-step) so the kernel is a pure function and byte-for-byte
@@ -200,15 +202,19 @@ def walk_step(
 # Fused multi-superstep kernel — the serving hot path
 # ---------------------------------------------------------------------------
 
+_LANES = 128       # int32 elements per DMA row of an (rows, 128) HBM view
+_LANE_BITS = 7     # log2(_LANES)
+_READS = 4         # row copies one gather phase issues per walker, at most
+_SMEM_BUDGET = 256 * 1024  # int32 words of SMEM a walk kernel may claim
+
 
 def _pick_edge(start, deg, r, use_b, fb, gate):
     """Sampled CSR edge index for one hop: uniform over [start, start+deg),
     or the personalized feat subrange when the bias draw fires and the
-    subrange is non-empty; 0 where ``gate`` is off.  Elementwise jnp — the
-    scalar gather path calls it with per-walker scalars, the dma path with
-    block vectors, so both modes share the ONE copy of the decision
-    arithmetic the bit-identity contract rests on.  ``fb`` is a (lo, hi)
-    bound pair, or None when biasing is off.
+    subrange is non-empty; 0 where ``gate`` is off.  Both gather modes and
+    the hop kernel call this ONE copy of the decision arithmetic the
+    bit-identity contract rests on.  ``fb`` is a (lo, hi) bound pair, or
+    None when biasing is off.
     """
     base, span = start, jnp.maximum(deg, 1)
     if fb is not None:
@@ -219,301 +225,271 @@ def _pick_edge(start, deg, r, use_b, fb, gate):
     return jnp.where(gate, base + r % span, 0)
 
 
-def _dma_row_gather(src_row, dst_ref, sem, n: int, extra=None):
-    """``dst_ref[i] <- src_row(i)`` for i < n, double-buffered async DMA.
+def _as_rows(x: jax.Array) -> jax.Array:
+    """View a CSR array as ``(rows, 128)`` int32 for row-granular DMA.
 
-    The copy for row i+1 is started before row i's is waited on, so two
-    copies are always in flight and each walker's HBM latency hides behind
-    its neighbour's.  Semaphore slots alternate (i % 2): waiting on row i
-    frees its slot just before row i+2 reuses it, and every start is
-    matched by a wait, so the pair leaves the phase balanced.
+    The TPU DMA engine addresses a 1-D int32 array in 1024-element tiles,
+    too coarse for one walker's element read; a ``(1, 128)`` row of the
+    2-D view is the narrowest window it fetches.  The reshape is free when
+    the length is a lane multiple (both layouts hold the same bytes);
+    otherwise the array is padded, which copies it.
+    """
+    flat = x.reshape(-1).astype(jnp.int32)
+    n = flat.shape[0]
+    pad = -n % _LANES if n else _LANES
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    return flat.reshape(-1, _LANES)
 
-    ``extra`` is an optional second (src_row, dst_ref, sem) triple gathered
-    in the SAME pipeline — its copies ride each iteration concurrently on
-    their own semaphore pair (how the bias-bound rows ride the offset
-    phase instead of paying a second drained pipeline).
+
+class _RowReader:
+    """Element reads from an ``(rows, 128)`` HBM view via SMEM row slots.
+
+    ``copy(e, k)`` is the DMA of the row holding element ``e`` into slot
+    ``k``; the row is clamped into bounds, so a stray address can never
+    fault the DMA engine.  ``value(e, k)`` reads the element once landed.
     """
 
-    def dma(i):
-        return pltpu.make_async_copy(src_row(i), dst_ref.at[i], sem.at[i % 2])
+    def __init__(self, ref, buf, sem):
+        self.ref, self.buf, self.sem = ref, buf, sem
 
-    def dma2(i):
-        src_row2, dst_ref2, sem2 = extra
+    def copy(self, e, k):
+        row = jnp.clip(e >> _LANE_BITS, 0, self.ref.shape[0] - 1)
         return pltpu.make_async_copy(
-            src_row2(i), dst_ref2.at[i], sem2.at[i % 2]
+            self.ref.at[pl.ds(row, 1)], self.buf.at[k], self.sem.at[k]
         )
 
-    dma(0).start()
-    if extra is not None:
-        dma2(0).start()
+    def value(self, e, k):
+        return self.buf[k, 0, e & (_LANES - 1)]
 
-    def body(i, carry):
-        @pl.when(i + 1 < n)
-        def _prefetch():
-            dma(i + 1).start()
-            if extra is not None:
-                dma2(i + 1).start()
 
-        dma(i).wait()
-        if extra is not None:
-            dma2(i).wait()
-        return carry
+def _run_phases(phases, n: int, gather_mode: str, buf, sem) -> None:
+    """Run per-walker gather phases over a block of ``n`` walkers.
 
-    jax.lax.fori_loop(0, n, body, 0)
+    A phase is ``(reads, finish)``: ``reads(i)`` lists the ``(reader,
+    element)`` pairs walker ``i`` needs from HBM (at most ``_READS``),
+    ``finish(i, values)`` consumes them with scalar arithmetic and leaves
+    its results in SMEM for the next phase.
+
+    * ``"scalar"`` — walker by walker, all phases of walker i before walker
+      i+1: each phase's row copies are started and waited on at once, one
+      exposed HBM round trip per phase per walker.
+    * ``"dma"``    — phase by phase over the whole block: walker i+1's
+      copies are started before walker i's are waited on (two slots of row
+      buffers), so one HBM latency hides behind the neighbour's.
+
+    Both run the same ``finish`` code on the same values.
+    """
+
+    def start(i, slot, reads):
+        for j, (rd, e) in enumerate(reads(i)):
+            rd.copy(e, slot * _READS + j).start()
+
+    def land(i, slot, reads, finish):
+        vals = []
+        for j, (rd, e) in enumerate(reads(i)):
+            k = slot * _READS + j
+            rd.copy(e, k).wait()
+            vals.append(rd.value(e, k))
+        finish(i, vals)
+
+    if gather_mode == "scalar":
+
+        def walker(i, carry):
+            for reads, finish in phases:
+                start(i, 0, reads)
+                land(i, 0, reads, finish)
+            return carry
+
+        jax.lax.fori_loop(0, n, walker, 0)
+        return
+
+    for reads, finish in phases:
+        start(0, 0, reads)
+
+        def body(i, carry, reads=reads, finish=finish):
+            @pl.when(i + 1 < n)
+            def _prefetch():
+                start(i + 1, (i + 1) % 2, reads)
+
+            land(i, i % 2, reads, finish)
+            return carry
+
+        jax.lax.fori_loop(0, n, body, 0)
+
+
+def _gather_scratch(n: int, n_state: int):
+    """SMEM row-buffer slots, their DMA semaphores, and ``n_state``
+    per-walker int32 SMEM vectors carrying results between phases."""
+    return [
+        pltpu.SMEM((2 * _READS, 1, _LANES), jnp.int32),
+        pltpu.SemaphoreType.DMA((2 * _READS,)),
+    ] + [pltpu.SMEM((n,), jnp.int32) for _ in range(n_state)]
+
+
+def _smem_spec(shape, index_map):
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.SMEM)
 
 
 def _walk_steps_fused_kernel(
     *refs,
     n_pins: int,
     n_slots: int,
-    n_boards: int,
     n_queries: int,
     alpha_u32: int,
     beta_u32: int,
     chunk_steps: int,
     block_w: int,
+    fb_width: int,
     use_bias: bool,
     count_boards: bool,
     gather_mode: str,
 ):
-    """chunk_steps supersteps for one walker block, state resident in VMEM.
+    """chunk_steps supersteps for one walker block, state resident in SMEM.
 
-    Ref layout (inputs then outputs; qid / query_events present only when
-    ``n_queries > 0``, bias bounds only if use_bias):
+    Ref layout (inputs, outputs, scratch; qid / query_events present only
+    when ``n_queries > 0``, bias bounds only if use_bias):
       curr, query, feat, slot, [qid], rbits,
       p2b_off, p2b_tgt, b2p_off, b2p_tgt, [p2b_fb, b2p_fb],
-      -> next, [query_events], slot_events, pin_events, [board_events]
+      -> next, [query_events], slot_events, pin_events, [board_events],
+      row buffers, semaphores, cur, pos, eidx, board_ok, b_local, bidx, ok
+
+    Walker state, the chunk's random bits (``(chunk_steps, block_w * 4)``,
+    walker-major quadruples) and the event blocks live in SMEM, where the
+    scalar unit reads and writes them at walker granularity; the CSR
+    arrays are ``(rows, 128)`` HBM views read only by row DMA.  A step is
+    four gather phases — hop-1 offset (+ bias-bound) rows, hop-1 target,
+    hop-2 offset (+ bias-bound) rows, hop-2 target — each finishing with
+    the decision arithmetic that produces the next phase's addresses.
 
     ``n_queries > 0`` is the batch-native mode: the walker block carries a
-    per-walker query id (which serving request of the batch the walker
-    belongs to) and each step additionally emits a query event lane — the
-    third wide lane of the (query, slot, pin) triple, sentinel
-    ``n_queries`` for invalid steps, sharing the slot lane's validity mask
-    exactly like the board lane does.  This is what lets ONE ``pallas_call``
-    execute a chunk for a whole serving batch instead of one call per query.
-
-    ``gather_mode`` picks how the per-walker CSR rows reach the compute:
-    blocking scalar loads ("scalar") or the phase-split double-buffered
-    async-copy pipeline ("dma").  Both modes share the random-bit decode
-    and event emission below, and do identical integer arithmetic on the
-    gathered rows — they are bit-for-bit interchangeable.
+    per-walker query id and each step additionally emits a query event
+    lane — sentinel ``n_queries`` for invalid steps, sharing the slot
+    lane's validity mask exactly like the board lane does.  This is what
+    lets ONE ``pallas_call`` execute a chunk for a whole serving batch.
     """
     with_query = n_queries > 0
-    curr_ref, query_ref, feat_ref, slot_ref = refs[:4]
-    i = 4
-    qid_ref = None
-    if with_query:
-        qid_ref = refs[i]
-        i += 1
-    (rbits_ref, p2b_off_ref, p2b_tgt_ref,
-     b2p_off_ref, b2p_tgt_ref) = refs[i:i + 5]
-    i += 5
-    if use_bias:
-        p2b_fb_ref, b2p_fb_ref = refs[i:i + 2]
-        i += 2
-    next_ref = refs[i]
-    i += 1
-    qev_ref = None
-    if with_query:
-        qev_ref = refs[i]
-        i += 1
-    sev_ref, pev_ref = refs[i:i + 2]
-    bev_ref = refs[i + 2] if count_boards else None
+    refs = list(refs)
 
-    # Walker state + the whole chunk's random bits: loaded into
-    # VREGs/VMEM once, resident for all chunk_steps supersteps.
-    query = query_ref[...]
-    slot = slot_ref[...]
-    feat = feat_ref[...]
-    qid = qid_ref[...] if with_query else None
-    rbits = rbits_ref[...]                       # (chunk_steps, block_w, 4)
-    # wide-event invalid sentinel: slot lane carries n_slots, value lanes 0
-    slot_sentinel = jnp.int32(n_slots)
-    query_sentinel = jnp.int32(n_queries)
+    def take(k):
+        out, refs[:k] = refs[:k], []
+        return out
 
-    def draws(s):
-        """Decode step s's random bits — shared by both gather modes."""
-        restart = rbits[s, :, 0] < jnp.uint32(alpha_u32)
-        use_b = rbits[s, :, 1] < jnp.uint32(beta_u32)
-        r_board = (rbits[s, :, 2] & jnp.uint32(_RMASK)).astype(jnp.int32)
-        r_pin = (rbits[s, :, 3] & jnp.uint32(_RMASK)).astype(jnp.int32)
-        return restart, use_b, r_board, r_pin
+    curr_ref, query_ref, feat_ref, slot_ref = take(4)
+    (qid_ref,) = take(1) if with_query else (None,)
+    (rbits_ref,) = take(1)
+    p2b_off, p2b_tgt, b2p_off, b2p_tgt = take(4)
+    p2b_fb, b2p_fb = take(2) if use_bias else (None, None)
+    (next_ref,) = take(1)
+    (qev_ref,) = take(1) if with_query else (None,)
+    sev_ref, pev_ref = take(2)
+    (bev_ref,) = take(1) if count_boards else (None,)
+    buf, sem, cur, pos, eidx, bok, bloc, bidx, okv = take(9)
 
-    def emit(s, carry, nxt, vis, bvis, okv):
-        """Wide (slot, pin) lane emission — the pin, board, and query lanes
-        share the slot lane (same validity mask)."""
-        _, qev, sev, pev, bev = carry
-        sev = sev.at[s].set(jnp.where(okv, slot, slot_sentinel))
-        pev = pev.at[s].set(jnp.where(okv, vis, 0))
-        if with_query:
-            qev = qev.at[s].set(jnp.where(okv, qid, query_sentinel))
-        if count_boards:
-            bev = bev.at[s].set(jnp.where(okv, bvis, 0))
-        return nxt, qev, sev, pev, bev
+    def reader(ref):
+        return None if ref is None else _RowReader(ref, buf, sem)
 
-    def one_step_scalar(s, carry):
-        curr = carry[0]
-        restart, use_b, r_board, r_pin = draws(s)
-        pos = jnp.where(restart, query, curr)
+    p2b_off_rd, p2b_tgt_rd = reader(p2b_off), reader(p2b_tgt)
+    b2p_off_rd, b2p_tgt_rd = reader(b2p_off), reader(b2p_tgt)
+    p2b_fb_rd, b2p_fb_rd = reader(p2b_fb), reader(b2p_fb)
 
-        # per-walker two-level CSR gather (data-dependent random access)
-        def walker(i, acc):
-            nxt, vis, bvis, okv = acc
-            p = pos[i]
-            off = p2b_off_ref[pl.ds(p, 2)]
-            start, deg = off[0], off[1] - off[0]
-            fb = None
+    def load_state(i, carry):
+        cur[i] = curr_ref[0, i]
+        return carry
+
+    jax.lax.fori_loop(0, block_w, load_state, 0)
+
+    def step(s, carry):
+        def draw(i, k):
+            return rbits_ref[s, i * 4 + k]
+
+        def pick_bits(i, k):
+            return (draw(i, k) & jnp.uint32(_RMASK)).astype(jnp.int32)
+
+        def use_b(i):
+            return draw(i, 1) < jnp.uint32(beta_u32)
+
+        def place(i, c):
+            restart = draw(i, 0) < jnp.uint32(alpha_u32)
+            pos[i] = jnp.where(restart, query_ref[0, i], cur[i])
+            return c
+
+        jax.lax.fori_loop(0, block_w, place, 0)
+
+        def row_reads(off_rd, fb_rd, node, i):
+            reads = [(off_rd, node), (off_rd, node + 1)]
             if use_bias:
-                fbr = p2b_fb_ref[pl.ds(p, 1), pl.ds(feat[i], 2)][0]
-                fb = (fbr[0], fbr[1])
+                f = node * fb_width + feat_ref[0, i]
+                reads += [(fb_rd, f), (fb_rd, f + 1)]
+            return reads
+
+        def bounds(v):
+            return (v[2], v[3]) if use_bias else None
+
+        def hop1_rows(i):
+            return row_reads(p2b_off_rd, p2b_fb_rd, pos[i], i)
+
+        def hop1_pick(i, v):
+            start, deg = v[0], v[1] - v[0]
             board_ok = deg > 0
-            eidx = _pick_edge(start, deg, r_board[i], use_b[i], fb, board_ok)
-            board = p2b_tgt_ref[pl.ds(eidx, 1)][0]
-            b_local = jnp.where(board_ok, board - n_pins, 0)
+            eidx[i] = _pick_edge(
+                start, deg, pick_bits(i, 2), use_b(i), bounds(v), board_ok
+            )
+            bok[i] = board_ok.astype(jnp.int32)
 
-            boff = b2p_off_ref[pl.ds(b_local, 2)]
-            bstart, bdeg = boff[0], boff[1] - boff[0]
-            bfb = None
-            if use_bias:
-                bfbr = b2p_fb_ref[pl.ds(b_local, 1), pl.ds(feat[i], 2)][0]
-                bfb = (bfbr[0], bfbr[1])
-            ok = board_ok & (bdeg > 0)
-            bidx = _pick_edge(bstart, bdeg, r_pin[i], use_b[i], bfb, ok)
-            pin = b2p_tgt_ref[pl.ds(bidx, 1)][0]
+        def hop1_target(i):
+            return [(p2b_tgt_rd, eidx[i])]
 
-            nxt = nxt.at[i].set(jnp.where(ok, pin, query[i]))
-            vis = vis.at[i].set(pin)
-            bvis = bvis.at[i].set(b_local)
-            okv = okv.at[i].set(ok)
-            return nxt, vis, bvis, okv
+        def hop1_land(i, v):
+            bloc[i] = jnp.where(bok[i] != 0, v[0] - n_pins, 0)
 
-        init = (
-            jnp.zeros((block_w,), jnp.int32),
-            jnp.zeros((block_w,), jnp.int32),
-            jnp.zeros((block_w,), jnp.int32),
-            jnp.zeros((block_w,), jnp.bool_),
+        def hop2_rows(i):
+            return row_reads(b2p_off_rd, b2p_fb_rd, bloc[i], i)
+
+        def hop2_pick(i, v):
+            bstart, bdeg = v[0], v[1] - v[0]
+            ok = (bok[i] != 0) & (bdeg > 0)
+            bidx[i] = _pick_edge(
+                bstart, bdeg, pick_bits(i, 3), use_b(i), bounds(v), ok
+            )
+            okv[i] = ok.astype(jnp.int32)
+
+        def hop2_target(i):
+            return [(b2p_tgt_rd, bidx[i])]
+
+        def hop2_land(i, v):
+            pin, ok = v[0], okv[i] != 0
+            cur[i] = jnp.where(ok, pin, query_ref[0, i])
+            # wide lane emission: pin, board and query lanes share the
+            # slot lane's validity mask
+            sev_ref[s, i] = jnp.where(ok, slot_ref[0, i], n_slots)
+            pev_ref[s, i] = jnp.where(ok, pin, 0)
+            if with_query:
+                qev_ref[s, i] = jnp.where(ok, qid_ref[0, i], n_queries)
+            if count_boards:
+                bev_ref[s, i] = jnp.where(ok, bloc[i], 0)
+
+        _run_phases(
+            [(hop1_rows, hop1_pick), (hop1_target, hop1_land),
+             (hop2_rows, hop2_pick), (hop2_target, hop2_land)],
+            block_w, gather_mode, buf, sem,
         )
-        nxt, vis, bvis, okv = jax.lax.fori_loop(0, block_w, walker, init)
-        return emit(s, carry, nxt, vis, bvis, okv)
+        return carry
 
-    def one_step_dma(s, carry, off_scr, tgt_scr, sem, fb_scr, fb_sem):
-        """Phase-split superstep: gather a whole hop's rows into VMEM
-        scratch via the double-buffered DMA pipeline, then run the hop's
-        decision arithmetic vectorized over the block.  Same arithmetic as
-        the scalar walker loop, phase by phase."""
-        curr = carry[0]
-        restart, use_b, r_board, r_pin = draws(s)
-        pos = jnp.where(restart, query, curr)
+    jax.lax.fori_loop(0, chunk_steps, step, 0)
 
-        # hop 1, offset phase: (start, end) rows; bias-bound rows ride the
-        # same pipeline on their own semaphore pair
-        _dma_row_gather(
-            lambda i: p2b_off_ref.at[pl.ds(pos[i], 2)], off_scr, sem, block_w,
-            extra=(
-                lambda i: p2b_fb_ref.at[pl.ds(pos[i], 1), pl.ds(feat[i], 2)],
-                fb_scr, fb_sem,
-            ) if use_bias else None,
-        )
-        off = off_scr[...]                            # (block_w, 2)
-        start, deg = off[:, 0], off[:, 1] - off[:, 0]
-        fb = None
-        if use_bias:
-            fbr = fb_scr[...]                         # (block_w, 1, 2)
-            fb = (fbr[:, 0, 0], fbr[:, 0, 1])
-        board_ok = deg > 0
-        eidx = _pick_edge(start, deg, r_board, use_b, fb, board_ok)
+    def store_state(i, carry):
+        next_ref[0, i] = cur[i]
+        return carry
 
-        # hop 1, target phase: the sampled board ids
-        _dma_row_gather(
-            lambda i: p2b_tgt_ref.at[pl.ds(eidx[i], 1)], tgt_scr, sem, block_w
-        )
-        board = tgt_scr[...][:, 0]
-        b_local = jnp.where(board_ok, board - n_pins, 0)
-
-        # hop 2, offset phase
-        _dma_row_gather(
-            lambda i: b2p_off_ref.at[pl.ds(b_local[i], 2)],
-            off_scr, sem, block_w,
-            extra=(
-                lambda i: b2p_fb_ref.at[
-                    pl.ds(b_local[i], 1), pl.ds(feat[i], 2)
-                ],
-                fb_scr, fb_sem,
-            ) if use_bias else None,
-        )
-        boff = off_scr[...]
-        bstart, bdeg = boff[:, 0], boff[:, 1] - boff[:, 0]
-        bfb = None
-        if use_bias:
-            bfbr = fb_scr[...]
-            bfb = (bfbr[:, 0, 0], bfbr[:, 0, 1])
-        ok = board_ok & (bdeg > 0)
-        bidx = _pick_edge(bstart, bdeg, r_pin, use_b, bfb, ok)
-
-        # hop 2, target phase: the sampled pin ids
-        _dma_row_gather(
-            lambda i: b2p_tgt_ref.at[pl.ds(bidx[i], 1)], tgt_scr, sem, block_w
-        )
-        pin = tgt_scr[...][:, 0]
-
-        nxt = jnp.where(ok, pin, query)
-        return emit(s, carry, nxt, pin, b_local, ok)
-
-    carry0 = (
-        curr_ref[...],
-        jnp.full(
-            (chunk_steps, block_w) if with_query else (1, 1),
-            query_sentinel, jnp.int32,
-        ),
-        jnp.full((chunk_steps, block_w), slot_sentinel, jnp.int32),
-        jnp.zeros((chunk_steps, block_w), jnp.int32),
-        jnp.zeros(
-            (chunk_steps, block_w) if count_boards else (1, 1), jnp.int32
-        ),
-    )
-
-    def finish(carry):
-        curr, qev, sev, pev, bev = carry
-        next_ref[...] = curr
-        if with_query:
-            qev_ref[...] = qev
-        sev_ref[...] = sev
-        pev_ref[...] = pev
-        if count_boards:
-            bev_ref[...] = bev
-
-    if gather_mode == "dma":
-
-        def scoped(off_scr, tgt_scr, sem, *fb_refs):
-            fb_scr, fb_sem = fb_refs if use_bias else (None, None)
-
-            def step(s, carry):
-                return one_step_dma(
-                    s, carry, off_scr, tgt_scr, sem, fb_scr, fb_sem
-                )
-
-            finish(jax.lax.fori_loop(0, chunk_steps, step, carry0))
-
-        scope = [
-            pltpu.VMEM((block_w, 2), jnp.int32),    # offset (start, end) rows
-            pltpu.VMEM((block_w, 1), jnp.int32),    # gathered target ids
-            pltpu.SemaphoreType.DMA((2,)),          # double-buffer pair
-        ]
-        if use_bias:
-            scope += [
-                pltpu.VMEM((block_w, 1, 2), jnp.int32),  # feat-bound rows
-                pltpu.SemaphoreType.DMA((2,)),
-            ]
-        pl.run_scoped(scoped, *scope)
-    else:
-        finish(jax.lax.fori_loop(0, chunk_steps, one_step_scalar, carry0))
+    jax.lax.fori_loop(0, block_w, store_state, 0)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "n_pins", "n_slots", "n_boards", "n_queries", "alpha_u32",
-        "beta_u32", "count_boards", "block_w", "gather_mode", "interpret",
+        "n_pins", "n_slots", "n_queries", "alpha_u32", "beta_u32",
+        "count_boards", "block_w", "gather_mode", "interpret",
     ),
 )
 def walk_steps_fused(
@@ -532,7 +508,6 @@ def walk_steps_fused(
     *,
     n_pins: int,
     n_slots: int,
-    n_boards: int,
     n_queries: int = 0,
     alpha_u32: int,
     beta_u32: int,
@@ -556,21 +531,20 @@ def walk_steps_fused(
     Aggregate with the tile-scan ``visit_counter`` kernels — no scatters
     anywhere on the hot path.
 
+    The kernel reads the feat bounds, like the CSR arrays, as flat
+    row-major int32 in ``(rows, 128)`` DMA rows.
+
     BATCH-NATIVE MODE: pass ``qid`` (per-walker query id) and
     ``n_queries > 0`` to run a whole serving batch's walkers in this one
     call.  The walker axis then packs all queries' pools back to back and
     the return grows a query event lane: ``(next_curr, query_events,
     slot_events, pin_events, board_events | None)`` — query lane sentinel
-    ``n_queries``, sharing the slot lane's validity mask.  The per-query
-    vmapped formulation lowers to one kernel per query (a batch-sized
-    leading grid dim under vmap); this mode is ONE ``pallas_call`` per
-    chunk with ``n_queries * w`` walker rows for the DMA pipeline to hide
-    latency behind.
+    ``n_queries``, sharing the slot lane's validity mask.  ONE
+    ``pallas_call`` per chunk with ``n_queries * w`` walker rows.
 
-    ``gather_mode="dma"`` replaces the blocking per-walker scalar CSR
-    gathers with the phase-split double-buffered ``make_async_copy``
-    pipeline (module docstring); bit-identical to ``"scalar"`` and to the
-    XLA reference, and interpret-safe on CPU hosts.
+    ``gather_mode`` picks how the CSR row copies are issued (module
+    docstring); the modes are bit-identical to each other and to the XLA
+    reference, and interpret-safe on CPU hosts.
     """
     if gather_mode not in GATHER_MODES:
         raise ValueError(
@@ -591,87 +565,70 @@ def walk_steps_fused(
         and b2p_feat_bounds is not None
         and beta_u32 > 0
     )
-    grid = (w // block_w,)
-    blk = lambda i: (i,)
-    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    n_lanes = 2 + int(with_query) + int(count_boards)
+    # pipelined SMEM blocks are double-buffered
+    smem_words = 2 * block_w * (
+        5 + 4 * chunk_steps + n_lanes * chunk_steps
+    ) + 7 * block_w + 2 * _READS * _LANES
+    if not interpret and smem_words > _SMEM_BUDGET:
+        raise ValueError(
+            f"walk block of {block_w} walkers x {chunk_steps} steps needs "
+            f"{smem_words} SMEM words (> {_SMEM_BUDGET}); lower "
+            "pallas_block_w or chunk_steps"
+        )
 
-    in_specs = [
-        pl.BlockSpec((block_w,), blk),                       # curr
-        pl.BlockSpec((block_w,), blk),                       # query
-        pl.BlockSpec((block_w,), blk),                       # feat
-        pl.BlockSpec((block_w,), blk),                       # slot
-    ]
-    args = [
-        curr.astype(jnp.int32),
-        query.astype(jnp.int32),
-        feat.astype(jnp.int32),
-        slot.astype(jnp.int32),
-    ]
+    # per-walker vectors ride as (1, w) rows: a 1-D SMEM operand would
+    # have to be blocked in 1024-element tiles
+    walker = _smem_spec((1, block_w), lambda i: (0, i))
+    in_specs = [walker] * 4                          # curr, query, feat, slot
+    args = [curr, query, feat, slot]
     if with_query:
-        in_specs.append(pl.BlockSpec((block_w,), blk))       # qid
-        args.append(qid.astype(jnp.int32))
-    in_specs += [
-        pl.BlockSpec((chunk_steps, block_w, 4), lambda i: (0, i, 0)),
-        any_spec, any_spec, any_spec, any_spec,              # CSR arrays
-    ]
-    args += [
-        rbits.astype(jnp.uint32),
-        p2b_offsets.astype(jnp.int32),
-        p2b_targets.astype(jnp.int32),
-        b2p_offsets.astype(jnp.int32),
-        b2p_targets.astype(jnp.int32),
-    ]
+        in_specs.append(walker)
+        args.append(qid)
+    args = [a.astype(jnp.int32).reshape(1, w) for a in args]
+    in_specs.append(_smem_spec((chunk_steps, block_w * 4), lambda i: (0, i)))
+    args.append(rbits.astype(jnp.uint32).reshape(chunk_steps, w * 4))
+    csr = [p2b_offsets, p2b_targets, b2p_offsets, b2p_targets]
     if use_bias:
-        in_specs += [any_spec, any_spec]
-        args += [
-            p2b_feat_bounds.astype(jnp.int32),
-            b2p_feat_bounds.astype(jnp.int32),
-        ]
+        csr += [p2b_feat_bounds, b2p_feat_bounds]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(csr)
+    args += [_as_rows(a) for a in csr]
 
-    ev_spec = pl.BlockSpec((chunk_steps, block_w), lambda i: (0, i))
+    ev_spec = _smem_spec((chunk_steps, block_w), lambda i: (0, i))
     ev_sds = jax.ShapeDtypeStruct((chunk_steps, w), jnp.int32)
-    out_specs = [pl.BlockSpec((block_w,), blk)]
-    out_shape = [jax.ShapeDtypeStruct((w,), jnp.int32)]
-    if with_query:
-        out_specs.append(ev_spec)
-        out_shape.append(ev_sds)
-    out_specs += [ev_spec, ev_spec]
-    out_shape += [ev_sds, ev_sds]
-    if count_boards:
-        out_specs.append(ev_spec)
-        out_shape.append(ev_sds)
+    out_specs = [walker] + [ev_spec] * n_lanes
+    out_shape = [jax.ShapeDtypeStruct((1, w), jnp.int32)] + [ev_sds] * n_lanes
 
     out = pl.pallas_call(
         functools.partial(
             _walk_steps_fused_kernel,
             n_pins=n_pins,
             n_slots=n_slots,
-            n_boards=n_boards,
             n_queries=n_queries,
             alpha_u32=alpha_u32,
             beta_u32=beta_u32,
             chunk_steps=chunk_steps,
             block_w=block_w,
+            fb_width=p2b_feat_bounds.shape[1] if use_bias else 0,
             use_bias=use_bias,
             count_boards=count_boards,
             gather_mode=gather_mode,
         ),
-        grid=grid,
+        grid=(w // block_w,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=_gather_scratch(block_w, 7),
         interpret=interpret,
     )(*args)
-    i = 1
-    qev = None
+    out = list(out)
+    nxt = out.pop(0).reshape(w)
+    qev = out.pop(0) if with_query else None
+    sev, pev = out.pop(0), out.pop(0)
+    bev = out.pop(0) if count_boards else None
     if with_query:
-        qev = out[i]
-        i += 1
-    sev, pev = out[i], out[i + 1]
-    bev = out[i + 2] if count_boards else None
-    if with_query:
-        return out[0], qev, sev, pev, bev
-    return out[0], sev, pev, bev
+        return nxt, qev, sev, pev, bev
+    return nxt, sev, pev, bev
 
 
 # ---------------------------------------------------------------------------
@@ -681,8 +638,9 @@ def walk_steps_fused(
 
 def _walk_hop_kernel(
     pos_ref, gate_ref, r_ref, base_ref,
-    off_ref, tgt_ref,            # shard-local CSR slice, HBM/ANY
+    off_ref, tgt_ref,            # shard-local CSR slice, (rows, 128) HBM
     out_ref, ok_ref,
+    buf, sem, eidx, okv,
     *,
     block_l: int,
     gather_mode: str,
@@ -693,65 +651,36 @@ def _walk_hop_kernel(
     replicated graph owns every row; the sharded engine must ``_route``
     walkers between hops, so this kernel is the fused kernel's per-hop
     half: the same ``_RMASK`` decode, the same ``_pick_edge`` arithmetic,
-    the same scalar/dma gather pipelines — over a shard-local CSR slice
-    whose rows are rebased by the traced ``row_base`` scalar (the
-    shard-local subrange offset, ``shard_id * rows_per_shard``).
+    the same gather phases — over a shard-local CSR slice whose rows are
+    rebased by the traced ``row_base`` scalar (the shard-local subrange
+    offset, ``shard_id * rows_per_shard``).
     """
-    pos = pos_ref[...]
-    gate = gate_ref[...] != 0
-    r = (r_ref[...] & jnp.uint32(_RMASK)).astype(jnp.int32)
-    row_base = base_ref[0]
-    # clamp non-gated walkers to row 0: their position may be a global id
-    # another shard owns (or a sentinel) — the result is masked anyway
-    local = jnp.where(gate, pos - row_base, 0)
+    off_rd = _RowReader(off_ref, buf, sem)
+    tgt_rd = _RowReader(tgt_ref, buf, sem)
 
-    if gather_mode == "dma":
+    def offset_rows(i):
+        # non-gated walkers read row 0: their position may be a global id
+        # another shard owns (or a sentinel) — the result is masked anyway
+        local = jnp.where(gate_ref[0, i] != 0, pos_ref[0, i] - base_ref[0], 0)
+        return [(off_rd, local), (off_rd, local + 1)]
 
-        def scoped(off_scr, tgt_scr, sem):
-            # offset phase: (start, end) rows, double-buffered
-            _dma_row_gather(
-                lambda i: off_ref.at[pl.ds(local[i], 2)], off_scr, sem,
-                block_l,
-            )
-            off = off_scr[...]                        # (block_l, 2)
-            start, deg = off[:, 0], off[:, 1] - off[:, 0]
-            ok = gate & (deg > 0)
-            eidx = _pick_edge(start, deg, r, False, None, ok)
-            # target phase: the sampled neighbour ids
-            _dma_row_gather(
-                lambda i: tgt_ref.at[pl.ds(eidx[i], 1)], tgt_scr, sem,
-                block_l,
-            )
-            tgt = tgt_scr[...][:, 0]
-            out_ref[...] = jnp.where(ok, tgt, 0)
-            ok_ref[...] = ok
+    def pick(i, v):
+        start, deg = v[0], v[1] - v[0]
+        ok = (gate_ref[0, i] != 0) & (deg > 0)
+        r = (r_ref[0, i] & jnp.uint32(_RMASK)).astype(jnp.int32)
+        eidx[i] = _pick_edge(start, deg, r, False, None, ok)
+        okv[i] = ok.astype(jnp.int32)
 
-        pl.run_scoped(
-            scoped,
-            pltpu.VMEM((block_l, 2), jnp.int32),
-            pltpu.VMEM((block_l, 1), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-        )
-    else:
+    def target(i):
+        return [(tgt_rd, eidx[i])]
 
-        def walker(i, acc):
-            out, okv = acc
-            off = off_ref[pl.ds(local[i], 2)]
-            start, deg = off[0], off[1] - off[0]
-            ok = gate[i] & (deg > 0)
-            eidx = _pick_edge(start, deg, r[i], False, None, ok)
-            t = tgt_ref[pl.ds(eidx, 1)][0]
-            out = out.at[i].set(jnp.where(ok, t, 0))
-            okv = okv.at[i].set(ok)
-            return out, okv
+    def land(i, v):
+        out_ref[0, i] = jnp.where(okv[i] != 0, v[0], 0)
+        ok_ref[0, i] = okv[i]
 
-        out, okv = jax.lax.fori_loop(
-            0, block_l, walker,
-            (jnp.zeros((block_l,), jnp.int32),
-             jnp.zeros((block_l,), jnp.bool_)),
-        )
-        out_ref[...] = out
-        ok_ref[...] = okv
+    _run_phases(
+        [(offset_rows, pick), (target, land)], block_l, gather_mode, buf, sem
+    )
 
 
 @functools.partial(
@@ -788,35 +717,28 @@ def walk_hop_fused(
     l = pos.shape[0]
     if l % block_l != 0:
         raise ValueError(f"walker count {l} must be a multiple of {block_l}")
-    grid = (l // block_l,)
-    blk = lambda i: (i,)
+    walker = _smem_spec((1, block_l), lambda i: (0, i))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    return pl.pallas_call(
+    tgt, ok = pl.pallas_call(
         functools.partial(
             _walk_hop_kernel, block_l=block_l, gather_mode=gather_mode
         ),
-        grid=grid,
+        grid=(l // block_l,),
         in_specs=[
-            pl.BlockSpec((block_l,), blk),           # pos
-            pl.BlockSpec((block_l,), blk),           # gate
-            pl.BlockSpec((block_l,), blk),           # r
-            pl.BlockSpec((1,), lambda i: (0,)),      # row_base
+            walker, walker, walker,                  # pos, gate, r
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # row_base
             any_spec, any_spec,                      # CSR slice
         ],
-        out_specs=[
-            pl.BlockSpec((block_l,), blk),
-            pl.BlockSpec((block_l,), blk),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((l,), jnp.int32),
-            jax.ShapeDtypeStruct((l,), jnp.bool_),
-        ],
+        out_specs=[walker, walker],
+        out_shape=[jax.ShapeDtypeStruct((1, l), jnp.int32)] * 2,
+        scratch_shapes=_gather_scratch(block_l, 2),
         interpret=interpret,
     )(
-        pos.astype(jnp.int32),
-        gate.astype(jnp.int32),
-        r.astype(jnp.uint32),
+        pos.astype(jnp.int32).reshape(1, l),
+        gate.astype(jnp.int32).reshape(1, l),
+        r.astype(jnp.uint32).reshape(1, l),
         jnp.asarray(row_base, jnp.int32).reshape((1,)),
-        offsets.astype(jnp.int32),
-        targets.astype(jnp.int32),
+        _as_rows(offsets),
+        _as_rows(targets),
     )
+    return tgt.reshape(l), ok.reshape(l) != 0

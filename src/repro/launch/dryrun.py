@@ -47,12 +47,9 @@ def run_cell(
         "arch": arch, "shape": shape, "mesh": mesh_kind,
         "n_chips": n_chips, "kind": cell_spec.kind, "status": "start",
     }
-    from repro.launch.mesh import set_mesh_compat
 
     t0 = time.time()
-    # jax.set_mesh is absent on the pinned JAX; every jit below gets explicit
-    # shardings, so the ambient mesh is optional there
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         # ---- 1. the REAL program: proof-of-compile + memory + schedule ----
         kw = {"n_micro": n_micro} if spec.family == "lm" else {}
         cell = build_cell(spec, cell_spec, mesh, **kw)

@@ -15,41 +15,21 @@ principle lifted to pod scope.
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def make_mesh_compat(shape, axes) -> Mesh:
-    """jax.make_mesh across JAX versions: AxisType / the axis_types kwarg
-    only exist on newer JAX; older versions take neither."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
-
-
-def set_mesh_compat(mesh: Mesh):
-    """jax.set_mesh across JAX versions.  Older JAX has neither set_mesh
-    nor sharding.use_mesh; callers there pass the mesh explicitly
-    (shard_map(mesh=...), jit shardings), so this degrades to a null
-    context."""
-    setter = getattr(jax, "set_mesh", None) or getattr(
-        jax.sharding, "use_mesh", None
-    )
-    if setter is not None:
-        return setter(mesh)
-    return contextlib.nullcontext()
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: shardings propagate through jit and
+    shard_map names its mesh explicitly (the default Explicit axes would
+    put every array's sharding into its type)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=None, axes=("data", "model")) -> Mesh:
@@ -63,7 +43,7 @@ def make_host_mesh(shape=None, axes=("data", "model")) -> Mesh:
             else:
                 break
         shape = (max(n // a, 1), a) if a <= n else (1, 1)
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh: Mesh):

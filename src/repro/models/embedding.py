@@ -26,7 +26,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models import layers
 
@@ -113,12 +112,12 @@ def lookup_sharded(
         vals = vals * mine[..., None].astype(vals.dtype)
         return jax.lax.psum(vals, axis_name=shard_axis)
 
-    return shard_map(
+    return jax.shard_map(
         local_lookup,
         mesh=mesh,
         in_specs=(P(shard_axis, None), P(bspec, None)),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
 
 

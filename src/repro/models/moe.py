@@ -203,7 +203,6 @@ def moe_ffn_sharded(
     Shared experts are NOT handled here (caller adds them; they are dense
     TP matmuls).  Returns (out (t, d), aux scalar).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     e, k = cfg.n_experts, cfg.top_k
@@ -283,7 +282,7 @@ def moe_ffn_sharded(
         out = jax.lax.psum(out, model_axis)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -294,7 +293,7 @@ def moe_ffn_sharded(
             P(model_axis, None, None),
         ),
         out_specs=(P(dspec, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
     return out, aux

@@ -29,15 +29,8 @@ import numpy as np
 PyTree = Any
 
 
-# jax.tree.flatten_with_path only exists on newer JAX; the pinned version
-# ships it under jax.tree_util only.
-_flatten_with_path = getattr(
-    jax.tree, "flatten_with_path", jax.tree_util.tree_flatten_with_path
-)
-
-
 def _flatten_with_names(tree: PyTree):
-    flat, treedef = _flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     names = ["/".join(str(k) for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]
     return names, leaves, jax.tree.structure(tree)

@@ -1,13 +1,13 @@
 """Fallback so property tests collect (and run) without `hypothesis`.
 
-The container image does not ship hypothesis; a bare `from hypothesis import
-...` aborts collection of the whole module, which under `pytest -x` kills the
-entire tier-1 run.  When the real library is available we re-export it
-untouched.  When it is missing, `given`/`settings`/`st` degrade to a tiny
-seeded-random sampler: each property test runs against a deterministic batch
-of random examples drawn from the same strategy shapes.  That is weaker than
-real shrinking-and-database hypothesis, but it keeps every property assertion
-exercised on every CI run instead of skipping the module wholesale.
+The pinned environment ships hypothesis (requirements-ci.txt), and then it
+is re-exported untouched.  On a host without it, a bare `from hypothesis
+import ...` would abort collection of the whole module; there `given` /
+`settings` / `st` degrade to a tiny seeded-random sampler: each property
+test runs against a deterministic batch of random examples drawn from the
+same strategy shapes.  That is weaker than real shrinking-and-database
+hypothesis, but it keeps every property assertion exercised instead of
+skipping the module wholesale.
 
 Only the strategy surface this repo uses is implemented: `st.integers`,
 `st.floats`, `st.booleans`, `st.sampled_from`, and (nested) `st.lists`.

@@ -17,10 +17,7 @@ def _run(n_devices: int, body: str) -> dict:
     """Execute `body` in a fresh python with n fake devices; body must print
     a single json object on its last line.
 
-    The prelude imports the version-compat shims `make_mesh_compat` (the
-    pinned JAX has no jax.sharding.AxisType / axis_types kwarg) and
-    `set_mesh_compat` (no jax.set_mesh; explicit mesh= arguments make the
-    ambient mesh unnecessary there, so it degrades to a null context) from
+    The prelude imports `make_mesh` (a mesh with Auto axes) from
     repro.launch.mesh.
     """
     prog = textwrap.dedent(f"""
@@ -30,7 +27,7 @@ def _run(n_devices: int, body: str) -> dict:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
@@ -47,14 +44,14 @@ def test_sharded_walk_agrees_with_replicated():
         from repro.graphs.synthetic import small_test_graph, top_degree_pins
         from repro.core import distributed as D, walk as W
         sg = small_test_graph()
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         shg = D.shard_graph(sg.graph, 2)
         qs = top_degree_pins(sg, 2)
         qp = jnp.asarray([int(qs[0]), int(qs[1]), -1, -1], jnp.int32)
         qw = jnp.asarray([1.0, 0.7, 0.0, 0.0], jnp.float32)
         cfg = D.ShardedWalkConfig(n_supersteps=64, walkers_per_shard=128,
                                   top_k=20)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             res = D.pixie_walk_sharded(shg, qp, qw, jax.random.key(0), cfg,
                                        mesh)
         wcfg = W.WalkConfig(n_steps=30000, n_walkers=256, bias_beta=0.0,
@@ -71,7 +68,7 @@ def test_sharded_walk_agrees_with_replicated():
 def test_sharded_embedding_lookup_matches_replicated():
     res = _run(4, """
         from repro.models import embedding as E
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = E.MegaTableConfig(feature_rows=(40, 24), dim=8,
                                 pad_to_multiple=8)
         table = jax.random.normal(jax.random.key(0),
@@ -81,7 +78,7 @@ def test_sharded_embedding_lookup_matches_replicated():
             jax.random.randint(jax.random.key(2), (16,), 0, 24),
         ], axis=1)
         want = E.lookup(table, ids, cfg)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             got = E.lookup_sharded(table, ids, cfg, mesh)
         err = float(jnp.abs(want - got).max())
         print(json.dumps({"max_err": err}))
@@ -96,7 +93,7 @@ def test_checkpoint_reshards_onto_different_mesh():
         import tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.training import checkpoint
-        mesh = make_mesh_compat((%d,), ("model",))
+        mesh = make_mesh((%d,), ("model",))
         x = jnp.arange(32.0).reshape(8, 4)
         sharded = jax.device_put(x, NamedSharding(mesh, P("model", None)))
         checkpoint.save("%s", 3, {"x": sharded})
@@ -116,7 +113,7 @@ def test_checkpoint_reshards_onto_different_mesh():
         res2 = _run(2, """
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.training import checkpoint
-            mesh = make_mesh_compat((2,), ("model",))
+            mesh = make_mesh((2,), ("model",))
             restored, step = checkpoint.restore(
                 "%s", {"x": jnp.zeros((8, 4))},
                 shardings={"x": NamedSharding(mesh, P("model", None))},
@@ -132,10 +129,9 @@ def test_checkpoint_reshards_onto_different_mesh():
 
 def test_compressed_psum_averages_across_shards():
     res = _run(4, """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.training import compression
-        mesh = make_mesh_compat((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         # per-shard gradients 0,1,2,3 -> mean 1.5
         g = jnp.repeat(jnp.arange(4.0)[:, None], 8, axis=1)
         r = jnp.zeros_like(g)
@@ -143,11 +139,11 @@ def test_compressed_psum_averages_across_shards():
             out, nr = compression.compressed_psum(
                 {"w": gg[0]}, {"w": rr[0]}, "data")
             return out["w"][None], nr["w"][None]
-        with set_mesh_compat(mesh):
-            out, _ = shard_map(f, mesh=mesh,
+        with jax.set_mesh(mesh):
+            out, _ = jax.shard_map(f, mesh=mesh,
                                in_specs=(P("data", None), P("data", None)),
                                out_specs=(P("data", None), P("data", None)),
-                               check_rep=False)(g, r)
+                               check_vma=False)(g, r)
         err = float(jnp.abs(out - 1.5).max())
         print(json.dumps({"max_err": err}))
     """)

@@ -184,20 +184,24 @@ def _fused_jaxpr(a, gather_mode):
         a["curr"], a["query"], a["feat"], a["slot"], a["rbits"],
         a["p2b_offsets"], a["p2b_targets"],
         a["b2p_offsets"], a["b2p_targets"],
-        n_pins=a["n_pins"], n_slots=a["n_slots"], n_boards=a["n_boards"],
+        n_pins=a["n_pins"], n_slots=a["n_slots"],
         alpha_u32=2**30, beta_u32=0, block_w=128,
         gather_mode=gather_mode, interpret=False,
     ))())
 
 
 def test_dma_mode_lowers_async_copies():
-    """The dma kernel really is a DMA pipeline: its (non-interpret) jaxpr
-    contains async-copy start/wait ops; the scalar kernel contains none."""
+    """Both modes read HBM only through async copies (the TPU cannot load
+    from an HBM ref); only the dma kernel pipelines them: its walker loop
+    starts walker i+1's copies under a guard (a cond) before waiting on
+    walker i's, while the scalar kernel waits on each copy at once."""
     a = _chunk_args(jax.random.key(5))
     dma_jaxpr = _fused_jaxpr(a, "dma")
-    assert "dma_start" in dma_jaxpr and "dma_wait" in dma_jaxpr
     scalar_jaxpr = _fused_jaxpr(a, "scalar")
-    assert "dma_start" not in scalar_jaxpr
+    for jaxpr in (dma_jaxpr, scalar_jaxpr):
+        assert "dma_start" in jaxpr and "dma_wait" in jaxpr
+    assert "cond[" in dma_jaxpr
+    assert "cond[" not in scalar_jaxpr
 
 
 def test_gather_mode_validated():
@@ -208,7 +212,7 @@ def test_gather_mode_validated():
             a["p2b_offsets"], a["p2b_targets"],
             a["b2p_offsets"], a["b2p_targets"],
             n_pins=a["n_pins"], n_slots=a["n_slots"],
-            n_boards=a["n_boards"], alpha_u32=0, beta_u32=0,
+            alpha_u32=0, beta_u32=0,
             gather_mode="bogus",
         )
     assert set(GATHER_MODES) == {"scalar", "dma"}

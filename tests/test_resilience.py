@@ -416,7 +416,7 @@ def _run(n_devices: int, body: str) -> dict:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
@@ -440,7 +440,7 @@ def test_dead_shard_kills_walkers_and_renormalizes():
 
         sg = small_test_graph()
         g = sg.graph
-        mesh = make_mesh_compat((2,), ("model",))
+        mesh = make_mesh((2,), ("model",))
         shg = D.shard_graph(g, 2)
         qs = top_degree_pins(sg, 4)
         cfg = W.WalkConfig(n_steps=1024, n_walkers=32, chunk_steps=4,
@@ -453,7 +453,7 @@ def test_dead_shard_kills_walkers_and_renormalizes():
         keys = jax.random.split(jax.random.key(0), 2)
         never = np.iinfo(np.int32).max
 
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             def walk(dead):
                 return jax.block_until_ready(D.pixie_walk_sharded_batched(
                     shg, jnp.asarray(pins), jnp.asarray(weights), keys,

@@ -39,9 +39,9 @@ from repro.core import distributed as dist_lib
 from repro.core import walk as walk_lib
 from repro.core.graph import build_graph
 from repro.graphs.synthetic import small_test_graph, top_degree_pins
-from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+from repro.kernels.introspect import full_buffer_reduces, iter_eqns
+from repro.launch.mesh import make_mesh
 from test_distributed import _run
-from test_earlystop_parity import _full_buffer_reduces, _iter_eqns
 
 
 # ---------------------------------------------------------------------------
@@ -56,7 +56,7 @@ _PARITY_BODY = """
     n_shards = %d
     sg = small_test_graph()
     g = sg.graph
-    mesh = make_mesh_compat(%s)
+    mesh = make_mesh(%s)
     shg = D.shard_graph(g, n_shards)
     qs = top_degree_pins(sg, 4)
     qp = jnp.asarray([[int(qs[0]), int(qs[1]), -1, -1],
@@ -69,7 +69,7 @@ _PARITY_BODY = """
                         n_p=30, n_v=3, bias_beta=0.0, count_boards=True)
 
     out = {}
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         for backend, gather in (("xla", "scalar"), ("pallas", "scalar"),
                                 ("pallas", "dma")):
             cfg = dataclasses.replace(base, backend=backend,
@@ -134,7 +134,7 @@ def test_route_drops_counted_and_zeroed_by_slack():
 
         sg = small_test_graph()
         g = sg.graph
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         shg = D.shard_graph(g, 2)
         qs = top_degree_pins(sg, 4)
         qp = jnp.asarray([[int(qs[0]), int(qs[1]), -1, -1],
@@ -147,7 +147,7 @@ def test_route_drops_counted_and_zeroed_by_slack():
                            n_p=10**9, n_v=10**9, bias_beta=0.0, top_k=25)
 
         out = {}
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             starved = S.serve_batch(shg, qp, qw, uf, key, cfg,
                                     with_stats=True, mesh=mesh, slack=0.05)
             roomy = S.serve_batch(shg, qp, qw, uf, key, cfg,
@@ -194,12 +194,12 @@ def test_pixie_server_serves_sharded_replica():
         from repro.serving.server import PixieServer
 
         sg = small_test_graph()
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         shg = D.shard_graph(sg.graph, 2)
         qs = [int(x) for x in top_degree_pins(sg, 4)]
         cfg = W.WalkConfig(n_steps=4096, n_walkers=128, chunk_steps=4,
                            n_p=10**9, n_v=10**9, bias_beta=0.0, top_k=15)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             srv = PixieServer(shg, cfg, batch_size=2, n_slots=4, seed=5,
                               mesh=mesh, slack=4.0)
             ref = PixieServer(sg.graph, cfg, batch_size=2, n_slots=4,
@@ -236,7 +236,7 @@ def test_pixie_server_serves_sharded_replica():
 
 def _traced_sharded_walk(n_queries, backend, count_boards=True):
     g = small_test_graph().graph
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     shg = dist_lib.shard_graph(g, 1)
     qp = jnp.tile(jnp.asarray([[3, 9, -1, -1]], jnp.int32), (n_queries, 1))
     qw = jnp.tile(
@@ -263,7 +263,7 @@ def test_superstep_pallas_calls_per_shard_not_per_query():
     for b in (1, 4):
         jaxpr, _, _ = _traced_sharded_walk(b, "pallas")
         n_calls[b] = sum(
-            1 for e in _iter_eqns(jaxpr) if e.primitive.name == "pallas_call"
+            1 for e in iter_eqns(jaxpr) if e.primitive.name == "pallas_call"
         )
     # 2 walk hops + visit counter + board counter per superstep trace
     assert n_calls[1] >= 4, n_calls
@@ -279,11 +279,11 @@ def test_sharded_while_body_has_no_full_buffer_reduction(backend):
     (query, slot, pin)-sized count buffer inside any while body."""
     n_queries = 2
     jaxpr, shg, _ = _traced_sharded_walk(n_queries, backend)
-    whiles = [e for e in _iter_eqns(jaxpr) if e.primitive.name == "while"]
+    whiles = [e for e in iter_eqns(jaxpr) if e.primitive.name == "while"]
     assert whiles, "sharded walk lost its chunk while loop?"
     n_bins = n_queries * 4 * shg.pins_per_shard
     for w in whiles:
-        found = _full_buffer_reduces(w.params["body_jaxpr"].jaxpr, n_bins)
+        found = full_buffer_reduces(w.params["body_jaxpr"].jaxpr, n_bins)
         assert not found, (
             f"sharded while body reduces a full count buffer on "
             f"{backend}: {found}"
@@ -294,7 +294,7 @@ def test_unrolled_cost_model_mode_is_loop_free():
     """launch/dryrun's cost-model mode (``unroll=True``) must contain no
     while/fori loops at all — XLA cost analysis needs a flat program."""
     g = small_test_graph().graph
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     shg = dist_lib.shard_graph(g, 1)
     qp = jnp.asarray([[3, 9, -1, -1]], jnp.int32)
     qw = jnp.asarray([[1.0, 0.5, 0.0, 0.0]], jnp.float32)
@@ -308,7 +308,7 @@ def test_unrolled_cost_model_mode_is_loop_free():
         )
     )(jax.random.split(jax.random.key(0), 1)).jaxpr
     assert not any(
-        e.primitive.name in ("while", "scan") for e in _iter_eqns(jaxpr)
+        e.primitive.name in ("while", "scan") for e in iter_eqns(jaxpr)
     )
 
 
@@ -379,7 +379,7 @@ def test_shard_graph_keeps_empty_local_rows():
     s, r = divmod(5, shg.boards_per_shard)   # board 5 has no pins
     assert boff[s, r + 1] - boff[s, r] == 0
     # a walk on the sharded graph with an empty-row query pin still runs
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     shg1 = dist_lib.shard_graph(g, 1)
     cfg = walk_lib.WalkConfig(
         n_steps=256, n_walkers=32, chunk_steps=4, n_p=10**9, n_v=10**9,

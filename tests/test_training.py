@@ -198,24 +198,21 @@ def test_quantize_dequantize_bounded_error():
 def test_compressed_psum_error_feedback():
     """Mean over the axis is preserved to within int8 quantization noise,
     and the residual carries the quantization error."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat, set_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    # the pinned JAX has neither jax.sharding.AxisType nor jax.set_mesh;
-    # shard_map receives the mesh explicitly so the ambient mesh is optional
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = {"w": jax.random.normal(jax.random.key(1), (64,))}
     r = compression.init_residual(g)
 
     def f(gg, rr):
         return compression.compressed_psum(gg, rr, "data")
 
-    with set_mesh_compat(mesh):
-        out, new_r = shard_map(
+    with jax.set_mesh(mesh):
+        out, new_r = jax.shard_map(
             f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(g, r)
     # single-device psum: reduced == dequant(quant(g)); residual = g - that
     np.testing.assert_allclose(

@@ -26,7 +26,7 @@ import pytest
 from repro.core import counter as counter_lib
 from repro.core import walk as walk_lib
 from repro.graphs.synthetic import sparse_wide_graph as _sparse_wide_graph
-from test_earlystop_parity import _iter_eqns
+from repro.kernels.introspect import iter_eqns
 
 
 # ---------------------------------------------------------------------------
@@ -84,11 +84,11 @@ def _walk_sorts_in_while_body(g, qp, qw, cfg, check_every, check_mode):
             check_every=check_every, check_mode=check_mode,
         )
     )(jax.random.key(0)).jaxpr
-    whiles = [e for e in _iter_eqns(jaxpr) if e.primitive.name == "while"]
+    whiles = [e for e in iter_eqns(jaxpr) if e.primitive.name == "while"]
     assert whiles, "event walk lost its while loop?"
     sizes = []
     for w in whiles:
-        for eqn in _iter_eqns(w.params["body_jaxpr"].jaxpr):
+        for eqn in iter_eqns(w.params["body_jaxpr"].jaxpr):
             if eqn.primitive.name == "sort":
                 sizes.append(
                     max(getattr(v.aval, "size", 0) for v in eqn.invars)

@@ -499,7 +499,8 @@ def serve_batch(
             )
         keys = key
     else:
-        keys = jax.random.split(key, pins.shape[0])
+        with jax.named_scope("pixie.query"):
+            keys = jax.random.split(key, pins.shape[0])
 
     from repro.core import distributed as dist_lib
 
@@ -524,12 +525,13 @@ def serve_batch(
                 "serve_batch over a ShardedGraph needs the device mesh "
                 "(pass mesh=...)"
             )
-        scores, ids, steps, n_high, dropped = (
-            dist_lib.recommend_sharded_batched(
-                graph, pins, weights, keys, cfg, mesh, axis, slack=slack,
-                shard_dead_at=shard_dead_at,
+        with jax.named_scope("pixie.walk"):
+            scores, ids, steps, n_high, dropped = (
+                dist_lib.recommend_sharded_batched(
+                    graph, pins, weights, keys, cfg, mesh, axis, slack=slack,
+                    shard_dead_at=shard_dead_at,
+                )
             )
-        )
         if with_stats:
             return scores, ids, steps, n_high, dropped
         return scores, ids
@@ -571,9 +573,10 @@ def serve_batch(
 
         if scenario is None:
             scenario = jnp.zeros((pins.shape[0],), jnp.int32)
-        scores, ids = ranker_lib.rank_candidates(
-            rank.params, rank.cfg, graph, ids, scores, scenario
-        )
+        with jax.named_scope("pixie.rank"):
+            scores, ids = ranker_lib.rank_candidates(
+                rank.params, rank.cfg, graph, ids, scores, scenario
+            )
     if with_stats:
         return scores, ids, steps, n_high
     return scores, ids

@@ -460,30 +460,27 @@ def pixie_random_walk(
         cfg.backend, n_slots, n_pins, n_boards_packed
     )
 
-    valid_q = (query_pins >= 0) & (query_weights > 0)
-    safe_q = jnp.where(valid_q, query_pins, 0)
-    degs = graph.pin_degree(safe_q) * valid_q.astype(graph.p2b.offsets.dtype)
+    with jax.named_scope("pixie.query"):
+        valid_q = (query_pins >= 0) & (query_weights > 0)
+        safe_q = jnp.where(valid_q, query_pins, 0)
+        degs = graph.pin_degree(safe_q) * valid_q.astype(
+            graph.p2b.offsets.dtype
+        )
 
-    # Eq. 1-2: per-slot step budgets; walker pool apportioned to match.
-    n_q = sampling.allocate_steps(
-        jnp.where(valid_q, query_weights, 0.0),
-        degs,
-        jnp.asarray(graph.max_pin_degree),
-        cfg.n_steps if step_budget is None
-        else jnp.minimum(jnp.asarray(step_budget, jnp.int32), cfg.n_steps),
-    )
-    slot_of_walker, _ = sampling.allocate_walkers(n_q, w)
-    query_of_walker = jnp.take(safe_q, slot_of_walker).astype(jnp.int32)
-
-    counts0 = jnp.zeros((n_slots * n_pins,), dtype=jnp.int32)
-    bcounts0 = (
-        jnp.zeros((n_slots * graph.n_boards,), dtype=jnp.int32)
-        if cfg.count_boards
-        else None
-    )
-    walkers_per_slot = jax.ops.segment_sum(
-        jnp.ones((w,), jnp.int32), slot_of_walker, num_segments=n_slots
-    )
+        # Eq. 1-2: per-slot step budgets; walker pool apportioned to match.
+        n_q = sampling.allocate_steps(
+            jnp.where(valid_q, query_weights, 0.0),
+            degs,
+            jnp.asarray(graph.max_pin_degree),
+            cfg.n_steps if step_budget is None
+            else jnp.minimum(jnp.asarray(step_budget, jnp.int32),
+                             cfg.n_steps),
+        )
+        slot_of_walker, _ = sampling.allocate_walkers(n_q, w)
+        query_of_walker = jnp.take(safe_q, slot_of_walker).astype(jnp.int32)
+        walkers_per_slot = jax.ops.segment_sum(
+            jnp.ones((w,), jnp.int32), slot_of_walker, num_segments=n_slots
+        )
 
     def cond(state):
         _, _, _, _, steps_taken, slot_active, it = state
@@ -494,22 +491,25 @@ def pixie_random_walk(
         step_base = it * cfg.chunk_steps
         walker_active = jnp.take(slot_active, slot_of_walker)
 
-        curr2, sev, pev, bev = _walk_chunk(
-            graph, curr, query_of_walker, user_feat, slot_of_walker,
-            key, step_base, cfg, n_slots,
-        )
+        with jax.named_scope("pixie.walk.hop"):
+            curr2, sev, pev, bev = _walk_chunk(
+                graph, curr, query_of_walker, user_feat, slot_of_walker,
+                key, step_base, cfg, n_slots,
+            )
         curr = jnp.where(walker_active, curr2, curr)
         # masking the shared slot lane invalidates pin AND board events
         sev = jnp.where(walker_active[None, :], sev, slot_sentinel)
         # fused: accumulate the chunk AND update the running n_high tally —
         # no n_slots * n_pins reduction anywhere in this loop body
-        counts, high = counter_lib.accumulate_packed_events_with_high(
-            counts, high, sev, pev, n_slots, n_pins, cfg.n_v, count_engine
-        )
-        if cfg.count_boards:
-            bcounts = counter_lib.accumulate_packed_events(
-                bcounts, sev, bev, n_slots, graph.n_boards, count_engine
+        with jax.named_scope("pixie.walk.count"):
+            counts, high = counter_lib.accumulate_packed_events_with_high(
+                counts, high, sev, pev, n_slots, n_pins, cfg.n_v,
+                count_engine,
             )
+            if cfg.count_boards:
+                bcounts = counter_lib.accumulate_packed_events(
+                    bcounts, sev, bev, n_slots, graph.n_boards, count_engine
+                )
 
         steps_taken = steps_taken + walkers_per_slot * slot_active.astype(
             jnp.int32
@@ -523,24 +523,33 @@ def pixie_random_walk(
         )
         return curr, counts, bcounts, high, steps_taken, slot_active, it + 1
 
-    state0 = (
-        query_of_walker,
-        counts0,
-        bcounts0,
-        jnp.zeros((n_slots,), jnp.int32),
-        jnp.zeros((n_slots,), jnp.int32),
-        valid_q,
-        jnp.asarray(0, jnp.int32),
-    )
-    curr, counts, bcounts, high, steps_taken, _, _ = jax.lax.while_loop(
-        cond, body, state0
-    )
-    per_slot = counts.reshape(n_slots, n_pins)
-    # never recommend the query pins themselves; the running tally counted
-    # a query pin that reached n_v, so zeroing it must also debit the tally
-    q_rows = jnp.arange(n_slots)
-    q_reached = (per_slot[q_rows, safe_q] >= cfg.n_v).astype(jnp.int32)
-    per_slot = per_slot.at[q_rows, safe_q].set(0)
+    with jax.named_scope("pixie.walk"):
+        counts0 = jnp.zeros((n_slots * n_pins,), dtype=jnp.int32)
+        bcounts0 = (
+            jnp.zeros((n_slots * graph.n_boards,), dtype=jnp.int32)
+            if cfg.count_boards
+            else None
+        )
+        state0 = (
+            query_of_walker,
+            counts0,
+            bcounts0,
+            jnp.zeros((n_slots,), jnp.int32),
+            jnp.zeros((n_slots,), jnp.int32),
+            valid_q,
+            jnp.asarray(0, jnp.int32),
+        )
+        curr, counts, bcounts, high, steps_taken, _, _ = jax.lax.while_loop(
+            cond, body, state0
+        )
+    with jax.named_scope("pixie.eq3"):
+        per_slot = counts.reshape(n_slots, n_pins)
+        # never recommend the query pins themselves; the running tally
+        # counted a query pin that reached n_v, so zeroing it must also
+        # debit the tally
+        q_rows = jnp.arange(n_slots)
+        q_reached = (per_slot[q_rows, safe_q] >= cfg.n_v).astype(jnp.int32)
+        per_slot = per_slot.at[q_rows, safe_q].set(0)
     return WalkResult(
         counts=per_slot,
         board_counts=None
@@ -591,8 +600,10 @@ def recommend_with_stats(
         graph, query_pins, query_weights, user_feat, key, cfg,
         step_budget=step_budget,
     )
-    boosted = counter_lib.boost_combine(res.counts)
-    scores, ids = counter_lib.topk_dense(boosted, cfg.top_k)
+    with jax.named_scope("pixie.eq3"):
+        boosted = counter_lib.boost_combine(res.counts)
+    with jax.named_scope("pixie.topk"):
+        scores, ids = counter_lib.topk_dense(boosted, cfg.top_k)
     return scores, ids, res.steps_taken, res.n_high
 
 
@@ -687,52 +698,49 @@ def pixie_random_walk_batched(
         cfg.backend, n_rows, n_pins, n_boards_packed
     )
 
-    valid_q = (query_pins >= 0) & (query_weights > 0)          # (B, S)
-    safe_q = jnp.where(valid_q, query_pins, 0)
-    degs = graph.pin_degree(safe_q) * valid_q.astype(graph.p2b.offsets.dtype)
-
-    # Eq. 1-2 per query — the same traced program the vmapped path runs
-    if step_budgets is None:
-        n_q = jax.vmap(
-            lambda v, qw, dg: sampling.allocate_steps(
-                jnp.where(v, qw, 0.0), dg,
-                jnp.asarray(graph.max_pin_degree), cfg.n_steps,
-            )
-        )(valid_q, query_weights, degs)                        # (B, S)
-    else:
-        n_q = jax.vmap(
-            lambda v, qw, dg, bt: sampling.allocate_steps(
-                jnp.where(v, qw, 0.0), dg,
-                jnp.asarray(graph.max_pin_degree), bt,
-            )
-        )(valid_q, query_weights, degs,
-          jnp.minimum(jnp.asarray(step_budgets, jnp.int32),
-                      cfg.n_steps))                            # (B, S)
-    slot_of_walker_q, _ = jax.vmap(
-        lambda nq: sampling.allocate_walkers(nq, w)
-    )(n_q)                                                     # (B, w)
-    query_of_walker_q = jax.vmap(jnp.take)(safe_q, slot_of_walker_q)
-    walkers_per_slot = jax.vmap(
-        lambda so: jax.ops.segment_sum(
-            jnp.ones((w,), jnp.int32), so, num_segments=n_slots
+    with jax.named_scope("pixie.query"):
+        valid_q = (query_pins >= 0) & (query_weights > 0)      # (B, S)
+        safe_q = jnp.where(valid_q, query_pins, 0)
+        degs = graph.pin_degree(safe_q) * valid_q.astype(
+            graph.p2b.offsets.dtype
         )
-    )(slot_of_walker_q).reshape(-1)                            # (B*S,)
 
-    # query-major walker packing: walkers of query q occupy [q*w, (q+1)*w)
-    qid_of_walker = jnp.repeat(jnp.arange(n_queries, dtype=jnp.int32), w)
-    slot_of_walker = slot_of_walker_q.reshape(-1).astype(jnp.int32)
-    query_of_walker = query_of_walker_q.reshape(-1).astype(jnp.int32)
-    feat_of_walker = jnp.repeat(jnp.asarray(user_feats, jnp.int32), w)
-    row_of_walker = qid_of_walker * n_slots + slot_of_walker
+        # Eq. 1-2 per query — the same traced program the vmapped path runs
+        if step_budgets is None:
+            n_q = jax.vmap(
+                lambda v, qw, dg: sampling.allocate_steps(
+                    jnp.where(v, qw, 0.0), dg,
+                    jnp.asarray(graph.max_pin_degree), cfg.n_steps,
+                )
+            )(valid_q, query_weights, degs)                    # (B, S)
+        else:
+            n_q = jax.vmap(
+                lambda v, qw, dg, bt: sampling.allocate_steps(
+                    jnp.where(v, qw, 0.0), dg,
+                    jnp.asarray(graph.max_pin_degree), bt,
+                )
+            )(valid_q, query_weights, degs,
+              jnp.minimum(jnp.asarray(step_budgets, jnp.int32),
+                          cfg.n_steps))                        # (B, S)
+        slot_of_walker_q, _ = jax.vmap(
+            lambda nq: sampling.allocate_walkers(nq, w)
+        )(n_q)                                                 # (B, w)
+        query_of_walker_q = jax.vmap(jnp.take)(safe_q, slot_of_walker_q)
+        walkers_per_slot = jax.vmap(
+            lambda so: jax.ops.segment_sum(
+                jnp.ones((w,), jnp.int32), so, num_segments=n_slots
+            )
+        )(slot_of_walker_q).reshape(-1)                        # (B*S,)
 
-    counts0 = jnp.zeros((n_rows * n_pins,), dtype=jnp.int32)
-    bcounts0 = (
-        jnp.zeros((n_rows * graph.n_boards,), dtype=jnp.int32)
-        if cfg.count_boards
-        else None
-    )
-    valid_row = valid_q.reshape(-1)
-    n_q_row = n_q.reshape(-1)
+        # query-major walker packing: walkers of query q occupy
+        # [q*w, (q+1)*w)
+        qid_of_walker = jnp.repeat(jnp.arange(n_queries, dtype=jnp.int32), w)
+        slot_of_walker = slot_of_walker_q.reshape(-1).astype(jnp.int32)
+        query_of_walker = query_of_walker_q.reshape(-1).astype(jnp.int32)
+        feat_of_walker = jnp.repeat(jnp.asarray(user_feats, jnp.int32), w)
+        row_of_walker = qid_of_walker * n_slots + slot_of_walker
+        valid_row = valid_q.reshape(-1)
+        n_q_row = n_q.reshape(-1)
 
     def cond(state):
         _, _, _, _, _, row_active, it = state
@@ -743,10 +751,11 @@ def pixie_random_walk_batched(
         step_base = it * cfg.chunk_steps
         walker_active = jnp.take(row_active, row_of_walker)
 
-        curr2, qev, sev, pev, bev = _walk_chunk_batched(
-            graph, curr, query_of_walker, feat_of_walker, slot_of_walker,
-            qid_of_walker, keys, step_base, cfg, n_slots, n_queries,
-        )
+        with jax.named_scope("pixie.walk.hop"):
+            curr2, qev, sev, pev, bev = _walk_chunk_batched(
+                graph, curr, query_of_walker, feat_of_walker, slot_of_walker,
+                qid_of_walker, keys, step_base, cfg, n_slots, n_queries,
+            )
         curr = jnp.where(walker_active, curr2, curr)
         # masking the shared lanes to the sentinel triple invalidates pin
         # AND board events of stopped queries/slots
@@ -755,15 +764,16 @@ def pixie_random_walk_batched(
         # fused: ONE call accumulates the whole batch's chunk AND updates
         # every (query, slot) running n_high tally — no per-query loop, no
         # n_rows * n_pins reduction anywhere in this body
-        counts, high = counter_lib.accumulate_packed_events_with_high(
-            counts, high, sev, pev, n_slots, n_pins, cfg.n_v, count_engine,
-            query_events=qev, n_queries=n_queries,
-        )
-        if cfg.count_boards:
-            bcounts = counter_lib.accumulate_packed_events(
-                bcounts, sev, bev, n_slots, graph.n_boards, count_engine,
-                query_events=qev, n_queries=n_queries,
+        with jax.named_scope("pixie.walk.count"):
+            counts, high = counter_lib.accumulate_packed_events_with_high(
+                counts, high, sev, pev, n_slots, n_pins, cfg.n_v,
+                count_engine, query_events=qev, n_queries=n_queries,
             )
+            if cfg.count_boards:
+                bcounts = counter_lib.accumulate_packed_events(
+                    bcounts, sev, bev, n_slots, graph.n_boards, count_engine,
+                    query_events=qev, n_queries=n_queries,
+                )
 
         steps_taken = steps_taken + walkers_per_slot * row_active.astype(
             jnp.int32
@@ -777,25 +787,35 @@ def pixie_random_walk_batched(
         )
         return curr, counts, bcounts, high, steps_taken, row_active, it + 1
 
-    state0 = (
-        query_of_walker,
-        counts0,
-        bcounts0,
-        jnp.zeros((n_rows,), jnp.int32),
-        jnp.zeros((n_rows,), jnp.int32),
-        valid_row,
-        jnp.asarray(0, jnp.int32),
-    )
-    curr, counts, bcounts, high, steps_taken, _, _ = jax.lax.while_loop(
-        cond, body, state0
-    )
-    per_slot = counts.reshape(n_queries, n_slots, n_pins)
-    # never recommend the query pins themselves; debit the tally like the
-    # per-query engine does
-    b_idx = jnp.arange(n_queries)[:, None]
-    s_idx = jnp.arange(n_slots)[None, :]
-    q_reached = (per_slot[b_idx, s_idx, safe_q] >= cfg.n_v).astype(jnp.int32)
-    per_slot = per_slot.at[b_idx, s_idx, safe_q].set(0)
+    with jax.named_scope("pixie.walk"):
+        counts0 = jnp.zeros((n_rows * n_pins,), dtype=jnp.int32)
+        bcounts0 = (
+            jnp.zeros((n_rows * graph.n_boards,), dtype=jnp.int32)
+            if cfg.count_boards
+            else None
+        )
+        state0 = (
+            query_of_walker,
+            counts0,
+            bcounts0,
+            jnp.zeros((n_rows,), jnp.int32),
+            jnp.zeros((n_rows,), jnp.int32),
+            valid_row,
+            jnp.asarray(0, jnp.int32),
+        )
+        curr, counts, bcounts, high, steps_taken, _, _ = jax.lax.while_loop(
+            cond, body, state0
+        )
+    with jax.named_scope("pixie.eq3"):
+        per_slot = counts.reshape(n_queries, n_slots, n_pins)
+        # never recommend the query pins themselves; debit the tally like
+        # the per-query engine does
+        b_idx = jnp.arange(n_queries)[:, None]
+        s_idx = jnp.arange(n_slots)[None, :]
+        q_reached = (per_slot[b_idx, s_idx, safe_q] >= cfg.n_v).astype(
+            jnp.int32
+        )
+        per_slot = per_slot.at[b_idx, s_idx, safe_q].set(0)
     return WalkResult(
         counts=per_slot,
         board_counts=None
@@ -828,10 +848,12 @@ def recommend_with_stats_batched(
         graph, query_pins, query_weights, user_feats, keys, cfg,
         step_budgets=step_budgets,
     )
-    boosted = jax.vmap(counter_lib.boost_combine)(res.counts)
-    scores, ids = jax.vmap(lambda b: counter_lib.topk_dense(b, cfg.top_k))(
-        boosted
-    )
+    with jax.named_scope("pixie.eq3"):
+        boosted = jax.vmap(counter_lib.boost_combine)(res.counts)
+    with jax.named_scope("pixie.topk"):
+        scores, ids = jax.vmap(
+            lambda b: counter_lib.topk_dense(b, cfg.top_k)
+        )(boosted)
     return scores, ids, res.steps_taken, res.n_high
 
 

@@ -30,7 +30,19 @@ synchronous flush loop:
 Latency accounting is per query: ``latency = queue wait + dispatch +
 compute`` (wait stamped at ``submit``, compute wall-clocked around the
 device round-trip).  ``ServerStats`` keeps bounded ring buffers — a
-long-lived replica never grows memory with traffic.  On CPU the Pallas
+long-lived replica never grows memory with traffic — beside integer
+counters of batch fill and of the walk steps early stopping left unspent.
+
+Host work is marked with ``jax.profiler.TraceAnnotation`` spans, on the
+device trace's clock when a profile is taken: ``pixie.submit`` (``req_id``;
+``lanes`` on ``submit_user``), ``pixie.dispatch`` (``batch_seq``,
+``n_real``, ``batch_size``, ``slots``, ``queued``) with its children
+``pixie.dispatch.form`` and ``pixie.dispatch.enqueue``, and per batch in
+``harvest`` ``pixie.harvest.wait``, ``.fetch`` and ``.assemble``
+(``batch_seq``).  The step itself carries ``jax.named_scope`` stages
+(``pixie.query``, ``pixie.walk`` around ``pixie.walk.hop`` and
+``pixie.walk.count``, ``pixie.eq3``, ``pixie.topk``, ``pixie.rank``; see
+core/walk.py and core/service.py).  On CPU the Pallas
 engine interprets, so the latency numbers measure plumbing; the
 benchmarks/bench_traffic.py agreement verdict is the regression signal.
 """
@@ -51,6 +63,15 @@ from repro.serving.resilience import ResilienceConfig, elastic_step_budget
 
 # "this shard never dies": the liveness sentinel for sharded replicas
 _NEVER_DIES = np.iinfo(np.int32).max
+
+_span = jax.profiler.TraceAnnotation
+
+
+def _steps_per_request(out):
+    """``(scores, ids, steps)`` from ``serve_batch(..., with_stats=True)``,
+    the walk steps summed over each request's query slots."""
+    scores, ids, steps = out[:3]
+    return scores, ids, jnp.sum(steps, axis=-1)
 
 
 class LatencyRing:
@@ -121,6 +142,14 @@ class ServerStats:
     compute_ms: LatencyRing = None
     queries: int = 0
     batches: int = 0
+    # batch fill: lanes (batch rows) dispatched, and those holding a real
+    # request rather than padding
+    lanes_dispatched: int = 0
+    lanes_filled: int = 0
+    # walk steps over answered requests, taken and budgeted (Eq. 2): the
+    # share early stopping (Algorithm 3) left unspent
+    steps_taken: int = 0
+    steps_budgeted: int = 0
     dropped: int = 0          # total refused work (rejections + harness drops)
     # submit-time admission rejections PER BUCKET (keyed by n_slots) —
     # previously these were folded into ``dropped`` with no bucket
@@ -165,14 +194,17 @@ class QueryResult:
     step total the request actually dispatched with (the full lane budget
     unless the resilience layer shed it; a multi-interest user reports
     the sum over its cluster lanes).  Degraded service is visible on the
-    result, never silent.
+    result, never silent.  ``steps_taken`` is the walk steps the request
+    actually took, summed over its query slots (and a user's lanes): less
+    than ``budget`` when early stopping ended the walk first.
     """
 
     __slots__ = ("req_id", "scores", "ids", "generation", "wait_ms",
-                 "compute_ms", "latency_ms", "batch_seq", "budget")
+                 "compute_ms", "latency_ms", "batch_seq", "budget",
+                 "steps_taken")
 
     def __init__(self, req_id, scores, ids, generation, wait_ms,
-                 compute_ms, batch_seq, budget=0):
+                 compute_ms, batch_seq, budget=0, steps_taken=0):
         self.req_id = req_id
         self.scores = scores
         self.ids = ids
@@ -182,6 +214,7 @@ class QueryResult:
         self.latency_ms = wait_ms + compute_ms
         self.batch_seq = batch_seq
         self.budget = budget
+        self.steps_taken = steps_taken
 
     def __iter__(self):
         return iter((self.scores, self.ids))
@@ -233,6 +266,7 @@ class _UserAssembly:
     compute_ms: float = 0.0
     batch_seq: int = -1
     budget: int = 0                  # summed dispatched lane budgets
+    steps_taken: int = 0             # summed lane walk steps
 
 
 @dataclasses.dataclass
@@ -240,6 +274,7 @@ class _InFlight:
     entries: List[_Pending]   # real requests only (padding not recorded)
     scores: jax.Array
     ids: jax.Array
+    steps: jax.Array          # (batch,) walk steps taken, summed over slots
     generation: int           # stamped at DISPATCH: swaps don't rewrite it
     t_dispatch: float         # logical clock (matches submit's ``now``)
     t_dispatch_wall: float    # wall clock, for the compute measurement
@@ -444,13 +479,18 @@ class PixieServer:
                 (graph.n_shards,), _NEVER_DIES, np.int32
             )
             sharded = jax.jit(
-                lambda pins, weights, feats, keys, dead: service.serve_batch(
-                    graph, pins, weights, feats, keys, cfg,
-                    mesh=mesh, axis=axis, slack=slack, shard_dead_at=dead,
+                lambda pins, weights, feats, keys, dead: _steps_per_request(
+                    service.serve_batch(
+                        graph, pins, weights, feats, keys, cfg, mesh=mesh,
+                        axis=axis, slack=slack, shard_dead_at=dead,
+                        with_stats=True,
+                    )
                 )
             )
-            self._serve = lambda _g, p, w, f, k: sharded(
-                p, w, f, k, jnp.asarray(self._shard_dead_at)
+            self._serve = self._keeping_steps(
+                lambda _g, p, w, f, k: sharded(
+                    p, w, f, k, jnp.asarray(self._shard_dead_at)
+                )
             )
             self._takes_budgets = False
         else:
@@ -471,10 +511,10 @@ class PixieServer:
                     # shedding can never retrace
                     self._plain_serve = jax.jit(
                         lambda graph, pins, weights, feats, keys, budgets:
-                            service.serve_batch(
+                            _steps_per_request(service.serve_batch(
                                 graph, pins, weights, feats, keys, cfg,
-                                step_budgets=budgets,
-                            )
+                                step_budgets=budgets, with_stats=True,
+                            ))
                     )
                 else:
                     # ranker params close over like cfg; scenario rides as
@@ -483,13 +523,26 @@ class PixieServer:
                     rank = self.ranker
                     self._plain_serve = jax.jit(
                         lambda graph, pins, weights, feats, keys, scen:
-                            service.serve_batch(
+                            _steps_per_request(service.serve_batch(
                                 graph, pins, weights, feats, keys, cfg,
-                                rank=rank, scenario=scen,
-                            )
+                                rank=rank, scenario=scen, with_stats=True,
+                            ))
                     )
-            self._serve = self._plain_serve
+            self._serve = self._keeping_steps(self._plain_serve)
             self._takes_budgets = self.ranker is None
+
+    def _keeping_steps(self, program):
+        """The serving step as ``_dispatch`` calls it: ``(scores, ids)``,
+        the contract a wrapper of ``_serve`` sees, while the same call's
+        per-request walk steps wait in ``self._steps`` for ``_dispatch``."""
+
+        def serve(*args):
+            scores, ids, self._steps = program(*args)
+            return scores, ids
+
+        # ahead-of-time compiles (pixiebench/rehearse.py) lower the program
+        serve.lower = getattr(program, "lower", None)
+        return serve
 
     # -- request path ---------------------------------------------------------
     def _route(self, n_pins: int) -> Tuple[int, int]:
@@ -538,64 +591,70 @@ class PixieServer:
         request's PRNG stream (``fold_in``), so a workload replayed with
         the same ids gets bit-identical walks regardless of batching.
         """
-        if len(weights) != len(pins):
-            raise ValueError(
-                f"query has {len(pins)} pins but {len(weights)} weights; "
-                "one weight per pin required (mismatched lengths silently "
-                "misalign weights to the wrong pins)"
-            )
-        if self.ranker is None:
-            if scenario != 0:
+        with _span("pixie.submit") as span:
+            if len(weights) != len(pins):
                 raise ValueError(
-                    f"scenario={scenario} on a retrieval-only server; pass "
-                    "ranker= to PixieServer to open the scenario axis"
+                    f"query has {len(pins)} pins but {len(weights)} weights; "
+                    "one weight per pin required (mismatched lengths silently "
+                    "misalign weights to the wrong pins)"
                 )
-        elif not 0 <= int(scenario) < self.ranker.cfg.n_scenarios:
-            raise ValueError(
-                f"scenario={scenario} out of range for heads "
-                f"{list(self.ranker.cfg.scenarios)}"
-            )
-        if budget is not None and not 1 <= int(budget) <= self.cfg.n_steps:
-            raise ValueError(
-                f"budget={budget} outside [1, cfg.n_steps="
-                f"{self.cfg.n_steps}]: the engine's chunk grid is sized "
-                "for cfg.n_steps and a zero-step walk is a drop"
-            )
-        if budget is not None and not getattr(self, "_takes_budgets", False):
-            raise ValueError(
-                "this replica's compiled program has no budgets axis "
-                "(ranked or sharded); per-request budgets need a plain "
-                "or multi-interest replica"
-            )
-        n = len(pins)
-        _, slots = self._route(n)
-        if now is None:
-            now = time.perf_counter()
-        if req_id is None:
-            req_id = self._seq
-            self._seq += 1
-        else:
-            self._seq = max(self._seq, req_id + 1)
-        queue = self._queues[slots]
-        if (self.max_queue_per_bucket is not None
-                and len(queue) >= self.max_queue_per_bucket):
-            # dropped stays the TOTAL refused-work counter; rejected is
-            # the per-bucket breakdown an operator needs to see WHICH
-            # shape is overloaded
-            self.stats.dropped += 1
-            self.stats.rejected[slots] = self.stats.rejected.get(slots, 0) + 1
-            return None
-        qp = np.full(slots, -1, np.int32)
-        qw = np.zeros(slots, np.float32)
-        qp[:n] = np.asarray(pins, np.int32)
-        qw[:n] = np.asarray(weights, np.float32)
-        queue.append(_Pending(
-            req_id=req_id, pins=qp, weights=qw, feat=int(user_feat),
-            key=jax.random.fold_in(self._key, req_id), t_enqueue=now,
-            scenario=int(scenario),
-            budget=0 if budget is None else int(budget),
-        ))
-        return req_id
+            if self.ranker is None:
+                if scenario != 0:
+                    raise ValueError(
+                        f"scenario={scenario} on a retrieval-only server; "
+                        "pass ranker= to PixieServer to open the scenario "
+                        "axis"
+                    )
+            elif not 0 <= int(scenario) < self.ranker.cfg.n_scenarios:
+                raise ValueError(
+                    f"scenario={scenario} out of range for heads "
+                    f"{list(self.ranker.cfg.scenarios)}"
+                )
+            if budget is not None and not 1 <= int(budget) <= self.cfg.n_steps:
+                raise ValueError(
+                    f"budget={budget} outside [1, cfg.n_steps="
+                    f"{self.cfg.n_steps}]: the engine's chunk grid is sized "
+                    "for cfg.n_steps and a zero-step walk is a drop"
+                )
+            if budget is not None and not getattr(self, "_takes_budgets",
+                                                  False):
+                raise ValueError(
+                    "this replica's compiled program has no budgets axis "
+                    "(ranked or sharded); per-request budgets need a plain "
+                    "or multi-interest replica"
+                )
+            n = len(pins)
+            _, slots = self._route(n)
+            if now is None:
+                now = time.perf_counter()
+            if req_id is None:
+                req_id = self._seq
+                self._seq += 1
+            else:
+                self._seq = max(self._seq, req_id + 1)
+            span.set_metadata(req_id=req_id)
+            queue = self._queues[slots]
+            if (self.max_queue_per_bucket is not None
+                    and len(queue) >= self.max_queue_per_bucket):
+                # dropped stays the TOTAL refused-work counter; rejected is
+                # the per-bucket breakdown an operator needs to see WHICH
+                # shape is overloaded
+                self.stats.dropped += 1
+                self.stats.rejected[slots] = (
+                    self.stats.rejected.get(slots, 0) + 1
+                )
+                return None
+            qp = np.full(slots, -1, np.int32)
+            qw = np.zeros(slots, np.float32)
+            qp[:n] = np.asarray(pins, np.int32)
+            qw[:n] = np.asarray(weights, np.float32)
+            queue.append(_Pending(
+                req_id=req_id, pins=qp, weights=qw, feat=int(user_feat),
+                key=jax.random.fold_in(self._key, req_id), t_enqueue=now,
+                scenario=int(scenario),
+                budget=0 if budget is None else int(budget),
+            ))
+            return req_id
 
     def submit_user(
         self,
@@ -625,62 +684,66 @@ class PixieServer:
         queue the WHOLE user sheds (returns None, one ``stats.dropped``) —
         partially-walked users would silently skew the merge.
         """
-        if self.pin_topics is None:
-            raise ValueError(
-                "submit_user needs a multi-interest replica; pass "
-                "pin_topics= to PixieServer to open the clustered intake"
+        with _span("pixie.submit") as span:
+            if self.pin_topics is None:
+                raise ValueError(
+                    "submit_user needs a multi-interest replica; pass "
+                    "pin_topics= to PixieServer to open the clustered intake"
+                )
+            uq = service.build_user_query(
+                actions, self.pin_topics, n_slots=self.max_slots,
+                n_clusters=self.n_clusters, half_life_hours=half_life_hours,
+                user_feat=user_feat,
             )
-        uq = service.build_user_query(
-            actions, self.pin_topics, n_slots=self.max_slots,
-            n_clusters=self.n_clusters, half_life_hours=half_life_hours,
-            user_feat=user_feat,
-        )
-        budgets = service.cluster_step_budgets(uq.importance, self.cfg.n_steps)
-        if now is None:
-            now = time.perf_counter()
-        if req_id is None:
-            req_id = self._seq
-            self._seq += 1
-        else:
-            self._seq = max(self._seq, req_id + 1)
-        # all-or-nothing admission: count this user's demand per bucket
-        lanes = []
-        demand: Dict[int, int] = {}
-        for ci in range(uq.n_clusters):
-            n = int(np.sum(uq.cluster_pins[ci] >= 0))
-            _, slots = self._route(n)
-            demand[slots] = demand.get(slots, 0) + 1
-            lanes.append((ci, slots, n))
-        if self.max_queue_per_bucket is not None:
-            for slots, extra in demand.items():
-                if len(self._queues[slots]) + extra > self.max_queue_per_bucket:
-                    self.stats.dropped += 1
-                    self.stats.rejected[slots] = (
-                        self.stats.rejected.get(slots, 0) + 1
-                    )
-                    return None
-        user_key = jax.random.fold_in(self._key, req_id)
-        for ci, slots, n in lanes:
-            # cluster rows fill valid entries first, so the prefix copy is
-            # the whole lane; padding past it is bit-invariant to the walk
-            qp = np.full(slots, -1, np.int32)
-            qw = np.zeros(slots, np.float32)
-            qp[:n] = uq.cluster_pins[ci][:n]
-            qw[:n] = uq.cluster_weights[ci][:n]
-            self._queues[slots].append(_Pending(
-                req_id=req_id, pins=qp, weights=qw, feat=int(user_feat),
-                key=jax.random.fold_in(user_key, ci), t_enqueue=now,
-                budget=int(budgets[ci]), user_id=req_id, cluster_idx=ci,
-            ))
-        self._users[req_id] = _UserAssembly(
-            n_clusters=uq.n_clusters,
-            importance=np.asarray(uq.importance, np.float32),
-            t_enqueue=now,
-            # stamped HERE, not at harvest: swap_graph's drain barrier
-            # guarantees every lane dispatches under this generation
-            generation=self.stats.graph_generation,
-        )
-        return req_id
+            budgets = service.cluster_step_budgets(uq.importance,
+                                                   self.cfg.n_steps)
+            if now is None:
+                now = time.perf_counter()
+            if req_id is None:
+                req_id = self._seq
+                self._seq += 1
+            else:
+                self._seq = max(self._seq, req_id + 1)
+            span.set_metadata(req_id=req_id, lanes=uq.n_clusters)
+            # all-or-nothing admission: count this user's demand per bucket
+            lanes = []
+            demand: Dict[int, int] = {}
+            for ci in range(uq.n_clusters):
+                n = int(np.sum(uq.cluster_pins[ci] >= 0))
+                _, slots = self._route(n)
+                demand[slots] = demand.get(slots, 0) + 1
+                lanes.append((ci, slots, n))
+            if self.max_queue_per_bucket is not None:
+                for slots, extra in demand.items():
+                    if (len(self._queues[slots]) + extra
+                            > self.max_queue_per_bucket):
+                        self.stats.dropped += 1
+                        self.stats.rejected[slots] = (
+                            self.stats.rejected.get(slots, 0) + 1
+                        )
+                        return None
+            user_key = jax.random.fold_in(self._key, req_id)
+            for ci, slots, n in lanes:
+                # cluster rows fill valid entries first, so the prefix copy is
+                # the whole lane; padding past it is bit-invariant to the walk
+                qp = np.full(slots, -1, np.int32)
+                qw = np.zeros(slots, np.float32)
+                qp[:n] = uq.cluster_pins[ci][:n]
+                qw[:n] = uq.cluster_weights[ci][:n]
+                self._queues[slots].append(_Pending(
+                    req_id=req_id, pins=qp, weights=qw, feat=int(user_feat),
+                    key=jax.random.fold_in(user_key, ci), t_enqueue=now,
+                    budget=int(budgets[ci]), user_id=req_id, cluster_idx=ci,
+                ))
+            self._users[req_id] = _UserAssembly(
+                n_clusters=uq.n_clusters,
+                importance=np.asarray(uq.importance, np.float32),
+                t_enqueue=now,
+                # stamped HERE, not at harvest: swap_graph's drain barrier
+                # guarantees every lane dispatches under this generation
+                generation=self.stats.graph_generation,
+            )
+            return req_id
 
     # -- batch formation ------------------------------------------------------
     def _dispatch(self, batch_size: int, slots: int, now: float) -> None:
@@ -691,6 +754,31 @@ class PixieServer:
         queue = self._queues[slots]
         entries = queue[:batch_size]
         del queue[:batch_size]
+        n_real = len(entries)
+        with _span("pixie.dispatch", batch_seq=self._batch_seq,
+                   n_real=n_real, batch_size=batch_size, slots=slots,
+                   queued=len(queue)):
+            with _span("pixie.dispatch.form"):
+                args, entry_budgets = self._form(entries, batch_size, slots,
+                                                 now)
+            t_wall = time.perf_counter()
+            with _span("pixie.dispatch.enqueue"):
+                scores, ids = self._serve(*args)
+            self._inflight.append(_InFlight(
+                entries=entries, scores=scores, ids=ids, steps=self._steps,
+                generation=self.stats.graph_generation,
+                t_dispatch=now, t_dispatch_wall=t_wall,
+                batch_seq=self._batch_seq, budgets=entry_budgets,
+            ))
+        self._batch_seq += 1
+        self.stats.batches += 1
+        self.stats.lanes_dispatched += batch_size
+        self.stats.lanes_filled += n_real
+
+    def _form(self, entries: List[_Pending], batch_size: int, slots: int,
+              now: float) -> Tuple[tuple, List[int]]:
+        """The serving step's arguments for one batch (padded to
+        ``batch_size``), and each real entry's dispatched Eq. 2 budget."""
         n_real = len(entries)
         pad = batch_size - n_real
         pins = np.full((batch_size, slots), -1, np.int32)
@@ -711,33 +799,22 @@ class PixieServer:
         )
         if self.ranker is not None:
             args += (jnp.asarray(scen),)
-        if self._takes_budgets:
-            rcfg = self.resilience
-            shed = rcfg is not None and rcfg.elastic
-            budgets = np.full((batch_size,), self.cfg.n_steps, np.int32)
-            for i, e in enumerate(entries):
-                b = e.budget if e.budget else self.cfg.n_steps
-                if shed:
-                    # deadline-aware elastic shed: queue wait on the
-                    # LOGICAL clock, so a chaos replay reproduces every
-                    # shrink bit-for-bit
-                    wait_ms = max(0.0, (now - e.t_enqueue) * 1e3)
-                    b = elastic_step_budget(b, wait_ms, rcfg)
-                budgets[i] = b
-            args += (jnp.asarray(budgets),)
-            entry_budgets = [int(budgets[i]) for i in range(n_real)]
-        else:
-            entry_budgets = [self.cfg.n_steps] * n_real
-        t_wall = time.perf_counter()
-        scores, ids = self._serve(*args)
-        self._inflight.append(_InFlight(
-            entries=entries, scores=scores, ids=ids,
-            generation=self.stats.graph_generation,
-            t_dispatch=now, t_dispatch_wall=t_wall,
-            batch_seq=self._batch_seq, budgets=entry_budgets,
-        ))
-        self._batch_seq += 1
-        self.stats.batches += 1
+        if not self._takes_budgets:
+            return args, [self.cfg.n_steps] * n_real
+        rcfg = self.resilience
+        shed = rcfg is not None and rcfg.elastic
+        budgets = np.full((batch_size,), self.cfg.n_steps, np.int32)
+        for i, e in enumerate(entries):
+            b = e.budget if e.budget else self.cfg.n_steps
+            if shed:
+                # deadline-aware elastic shed: queue wait on the LOGICAL
+                # clock, so a chaos replay reproduces every shrink
+                # bit-for-bit
+                wait_ms = max(0.0, (now - e.t_enqueue) * 1e3)
+                b = elastic_step_budget(b, wait_ms, rcfg)
+            budgets[i] = b
+        args += (jnp.asarray(budgets),)
+        return args, [int(budgets[i]) for i in range(n_real)]
 
     def _deadline_of(self, entry: _Pending) -> float:
         """Logical dispatch deadline of one queued request.  The SINGLE
@@ -789,33 +866,16 @@ class PixieServer:
         """
         out: List[QueryResult] = []
         for fl in self._inflight:
-            jax.block_until_ready(fl.scores)
+            with _span("pixie.harvest.wait", batch_seq=fl.batch_seq):
+                jax.block_until_ready(fl.scores)
             t_done_wall = time.perf_counter()
             compute_ms = (t_done_wall - fl.t_dispatch_wall) * 1e3
-            s_np, i_np = np.asarray(fl.scores), np.asarray(fl.ids)
-            for i, e in enumerate(fl.entries):
-                wait_ms = max(0.0, (fl.t_dispatch - e.t_enqueue) * 1e3)
-                if e.user_id is not None:
-                    # a cluster lane: park it in the user's assembly; the
-                    # merged user-level result is emitted below once every
-                    # lane has returned
-                    asm = self._users[e.user_id]
-                    asm.parts[e.cluster_idx] = (s_np[i], i_np[i])
-                    asm.wait_ms = max(asm.wait_ms, wait_ms)
-                    asm.compute_ms = max(asm.compute_ms, compute_ms)
-                    asm.batch_seq = max(asm.batch_seq, fl.batch_seq)
-                    asm.budget += fl.budgets[i]
-                    continue
-                out.append(QueryResult(
-                    req_id=e.req_id, scores=s_np[i], ids=i_np[i],
-                    generation=fl.generation, wait_ms=wait_ms,
-                    compute_ms=compute_ms, batch_seq=fl.batch_seq,
-                    budget=fl.budgets[i],
-                ))
-                self.stats.queries += 1
-                self.stats.wait_ms.append(wait_ms)
-                self.stats.compute_ms.append(compute_ms)
-                self.stats.latencies_ms.append(wait_ms + compute_ms)
+            with _span("pixie.harvest.fetch", batch_seq=fl.batch_seq):
+                s_np, i_np, st_np = jax.device_get(
+                    (fl.scores, fl.ids, fl.steps)
+                )
+            with _span("pixie.harvest.assemble", batch_seq=fl.batch_seq):
+                out += self._assemble(fl, s_np, i_np, st_np, compute_ms)
         self._inflight = []
         # emit users whose lanes all returned: Eq. 3 across clusters via
         # the SAME bit-reproducible merge the fused service path uses.
@@ -826,26 +886,68 @@ class PixieServer:
         # latency sample per USER, not per lane.
         done = [rid for rid, a in self._users.items()
                 if len(a.parts) == a.n_clusters]
-        for rid in sorted(done):
-            asm = self._users.pop(rid)
-            scores = jnp.asarray(
-                np.stack([asm.parts[c][0] for c in range(asm.n_clusters)])
-            )
-            ids = jnp.asarray(
-                np.stack([asm.parts[c][1] for c in range(asm.n_clusters)])
-            )
-            ms, mi = self._merge(scores, ids, jnp.asarray(asm.importance))
-            out.append(QueryResult(
-                req_id=rid, scores=np.asarray(ms), ids=np.asarray(mi),
-                generation=asm.generation, wait_ms=asm.wait_ms,
-                compute_ms=asm.compute_ms, batch_seq=asm.batch_seq,
-                budget=asm.budget,
-            ))
-            self.stats.queries += 1
-            self.stats.wait_ms.append(asm.wait_ms)
-            self.stats.compute_ms.append(asm.compute_ms)
-            self.stats.latencies_ms.append(asm.wait_ms + asm.compute_ms)
+        if done:
+            with _span("pixie.harvest.assemble", users=len(done)):
+                for rid in sorted(done):
+                    out.append(self._merge_user(rid))
         return out
+
+    def _assemble(self, fl: _InFlight, s_np: np.ndarray, i_np: np.ndarray,
+                  st_np: np.ndarray, compute_ms: float) -> List[QueryResult]:
+        """Results of one harvested batch; a multi-interest user's cluster
+        lanes are parked in its assembly instead."""
+        out = []
+        for i, e in enumerate(fl.entries):
+            wait_ms = max(0.0, (fl.t_dispatch - e.t_enqueue) * 1e3)
+            if e.user_id is not None:
+                # a cluster lane: park it in the user's assembly; the
+                # merged user-level result is emitted once every lane has
+                # returned
+                asm = self._users[e.user_id]
+                asm.parts[e.cluster_idx] = (s_np[i], i_np[i])
+                asm.wait_ms = max(asm.wait_ms, wait_ms)
+                asm.compute_ms = max(asm.compute_ms, compute_ms)
+                asm.batch_seq = max(asm.batch_seq, fl.batch_seq)
+                asm.budget += fl.budgets[i]
+                asm.steps_taken += int(st_np[i])
+                continue
+            out.append(QueryResult(
+                req_id=e.req_id, scores=s_np[i], ids=i_np[i],
+                generation=fl.generation, wait_ms=wait_ms,
+                compute_ms=compute_ms, batch_seq=fl.batch_seq,
+                budget=fl.budgets[i], steps_taken=int(st_np[i]),
+            ))
+            self._account(out[-1])
+        return out
+
+    def _merge_user(self, rid: int) -> QueryResult:
+        """The merged result of a user whose lanes have all returned."""
+        asm = self._users.pop(rid)
+        scores = jnp.asarray(
+            np.stack([asm.parts[c][0] for c in range(asm.n_clusters)])
+        )
+        ids = jnp.asarray(
+            np.stack([asm.parts[c][1] for c in range(asm.n_clusters)])
+        )
+        ms, mi = self._merge(scores, ids, jnp.asarray(asm.importance))
+        res = QueryResult(
+            req_id=rid, scores=np.asarray(ms), ids=np.asarray(mi),
+            generation=asm.generation, wait_ms=asm.wait_ms,
+            compute_ms=asm.compute_ms, batch_seq=asm.batch_seq,
+            budget=asm.budget, steps_taken=asm.steps_taken,
+        )
+        self._account(res)
+        return res
+
+    def _account(self, res: QueryResult) -> None:
+        """One answered request into the stats."""
+        st = self.stats
+        st.queries += 1
+        st.wait_ms.append(res.wait_ms)
+        st.compute_ms.append(res.compute_ms)
+        st.latencies_ms.append(res.latency_ms)
+        st.steps_taken += res.steps_taken
+        st.steps_budgeted += res.budget
 
     def flush(self, now: Optional[float] = None) -> List[QueryResult]:
         """Serve every queued request synchronously (padding partials).

@@ -7,9 +7,9 @@ Quantifies the batching tentpole on the serving path
 one walker axis, ONE fused ``pallas_call`` + ONE query-major counting call
 per superstep chunk, a shared while loop with a per-(query, slot)
 early-stop mask — swept over batch {1, 4, 16, 64} x gather mode, with two
-controls: the vmapped per-query XLA engine (serve_batch's
-``backend="xla"`` twin) and the vmapped per-query *pallas* engine (what
-serve_batch used to do: vmap prepends the batch to every kernel grid).
+controls, each the vmapped per-query engine called directly: on XLA (the
+oracle) and on *pallas* (what serve_batch used to do: vmap prepends the
+batch to every kernel grid).
 
 The sweep holds SERVER CAPACITY fixed — a constant total walker pool and
 step budget split evenly across the batch (the paper's serving framing: a
@@ -129,19 +129,19 @@ def _sweep(seed: int) -> Dict:
                 with_stats=True,
             ))
 
-        def vmapped_pallas():
-            pcfg = dataclasses.replace(cfg, backend="pallas")
+        def vmapped(backend):
+            vcfg = dataclasses.replace(cfg, backend=backend)
             return jax.jit(lambda ks: jax.vmap(
                 lambda qp, qw, uf, k: walk_lib.recommend_with_stats(
-                    g, qp, qw, uf, k, pcfg
+                    g, qp, qw, uf, k, vcfg
                 )
             )(pins, weights, feats, ks))
 
         engines = {
-            "xla_vmapped": (serve("xla", "scalar"), key),
+            "xla_vmapped": (vmapped("xla"), keys),
             "pallas_batched_scalar": (serve("pallas", "scalar"), key),
             "pallas_batched_dma": (serve("pallas", "dma"), key),
-            "pallas_vmapped": (vmapped_pallas(), keys),
+            "pallas_vmapped": (vmapped("pallas"), keys),
         }
         for label, (fn, arg) in engines.items():
             t = timed(fn, arg, warmup=1, iters=2)

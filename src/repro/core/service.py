@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import distributed as dist_lib
 from repro.core import walk as walk_lib
 
 ACTION_WEIGHTS: Dict[str, float] = {
@@ -381,6 +382,29 @@ def batch_user_queries(
     )
 
 
+def walk_engine(graph, batch: int, n_slots: int,
+                cfg: walk_lib.WalkConfig) -> str:
+    """The walk formulation ``serve_batch`` runs for a (graph, batch) shape.
+
+    ``"sharded"`` for a ``distributed.ShardedGraph`` (the pod-sharded
+    engine); ``"batched"`` when the batch-native engine's query-major bins
+    fit int32 indexing (``walk_lib.batched_engine_fits``); ``"vmapped"``
+    past that envelope.  Both unsharded formulations give bit-identical
+    answers; the batch-native one moves less data, because a vmapped
+    per-query ``while_loop`` gets a batched predicate and so selects (and
+    copies) every query's whole count carry each chunk.  The choice reads
+    only shapes and the graph's type: ``cfg.backend`` picks the hop and
+    count implementations inside either loop.
+    """
+    if isinstance(graph, dist_lib.ShardedGraph):
+        return "sharded"
+    if walk_lib.batched_engine_fits(
+        batch, n_slots, graph.n_pins, graph.n_boards, cfg.count_boards
+    ):
+        return "batched"
+    return "vmapped"
+
+
 def serve_batch(
     graph,
     pins: jnp.ndarray,      # (batch, n_slots)
@@ -409,18 +433,19 @@ def serve_batch(
     early-stop observables, since both maintain the same incremental
     ``n_high`` tally.
 
-    ``backend="pallas"`` routes through the BATCH-NATIVE engine
-    (``walk_lib.recommend_with_stats_batched``): the whole batch's walkers
-    run in one fused ``pallas_call`` per superstep chunk and counting is
-    one query-major call per chunk, instead of a batch-sized grid
-    replication per query under vmap.  ``backend="xla"`` keeps the vmapped
-    per-query path — the oracle twin the batched engine is verified
-    bit-identical against (tests/test_batchfuse.py).  The batched engine's
-    query-major bins must fit int32 indexing
-    (``walk_lib.batched_engine_fits``); a (graph, batch) shape past that
-    envelope falls back to the vmapped formulation — same results, the
-    per-query bins may still fit — rather than erroring where the old
-    path served.
+    Which walk formulation runs is ``walk_engine``'s choice, by shape
+    alone.  Every unsharded batch whose query-major bins fit int32
+    indexing (``walk_lib.batched_engine_fits``) runs the BATCH-NATIVE
+    engine (``walk_lib.recommend_with_stats_batched``) on either backend:
+    the whole batch's walkers share one ``while_loop`` with a scalar
+    predicate, each superstep chunk is one hop call (one fused
+    ``pallas_call`` on ``backend="pallas"``) and one query-major counting
+    call, and the dense count carry is updated in place.  A (graph, batch)
+    shape past that envelope falls back to vmapping the per-query engine
+    — same results, the per-query bins may still fit — rather than
+    erroring where it would serve.  The vmapped formulation is the oracle
+    the batched engine is verified bit-identical against
+    (tests/test_batchfuse.py).
 
     ``key`` is either a scalar PRNG key — split into one stream per query,
     the original behavior — or a ``(batch,)`` typed key array used
@@ -502,9 +527,8 @@ def serve_batch(
         with jax.named_scope("pixie.query"):
             keys = jax.random.split(key, pins.shape[0])
 
-    from repro.core import distributed as dist_lib
-
-    if isinstance(graph, dist_lib.ShardedGraph):
+    engine = walk_engine(graph, int(pins.shape[0]), int(pins.shape[1]), cfg)
+    if engine == "sharded":
         if step_budgets is not None:
             raise ValueError(
                 "serve_batch(step_budgets=...) over a ShardedGraph is not "
@@ -541,10 +565,7 @@ def serve_batch(
             "serve_batch(shard_dead_at=...) needs a ShardedGraph: an "
             "unsharded replica has no shards to lose"
         )
-    if cfg.backend == "pallas" and walk_lib.batched_engine_fits(
-        int(pins.shape[0]), int(pins.shape[1]), graph.n_pins,
-        graph.n_boards, cfg.count_boards,
-    ):
+    if engine == "batched":
         scores, ids, steps, n_high = walk_lib.recommend_with_stats_batched(
             graph, pins, weights, user_feats, keys, cfg,
             step_budgets=step_budgets,

@@ -142,6 +142,9 @@ class ServerStats:
     compute_ms: LatencyRing = None
     queries: int = 0
     batches: int = 0
+    # batches dispatched on the batch-native walk engine
+    # (``service.walk_engine``); the rest ran vmapped or sharded
+    batches_batch_native: int = 0
     # batch fill: lanes (batch rows) dispatched, and those holding a real
     # request rather than padding
     lanes_dispatched: int = 0
@@ -772,6 +775,9 @@ class PixieServer:
             ))
         self._batch_seq += 1
         self.stats.batches += 1
+        if service.walk_engine(self.graph, batch_size, slots,
+                               self.cfg) == "batched":
+            self.stats.batches_batch_native += 1
         self.stats.lanes_dispatched += batch_size
         self.stats.lanes_filled += n_real
 

@@ -15,19 +15,30 @@ contains a constant number of ``pallas_call`` eqns inside one
 ``max_chunks``-bounded while loop, with NO batch-sized leading grid
 dimension — the vmapped pallas path (the positive control) prepends the
 batch to every kernel grid, i.e. batch x chunks program replication.
+
+``serve_batch`` runs the batched engine on BOTH backends whenever its
+bins fit int32 (``service.walk_engine``), so its answers are checked
+against the vmapped engine called directly, never through
+``serve_batch``; the xla step's lowering is pinned to one flat count
+carry with a scalar loop predicate, and the vmapped fallback to the
+batched predicate that selects the whole carry each chunk.
 """
 
 import dataclasses
+import re
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import distributed as dist_lib
 from repro.core import service, walk as walk_lib
 from repro.graphs.synthetic import small_test_graph, top_degree_pins
-from repro.kernels.introspect import pallas_grids
+from repro.kernels.introspect import iter_eqns, pallas_grids
 from repro.kernels.walk_step import DEFAULT_BLOCK_W
+from repro.serving.server import PixieServer
 
 
 @pytest.fixture(scope="module")
@@ -139,25 +150,61 @@ def test_queries_early_stop_at_different_chunks(sg):
     assert (per_query_steps < cfg.n_steps).any()
 
 
-def test_serve_batch_routes_pallas_through_batched_engine(sg):
-    """serve_batch backend="pallas" (batched) == backend="xla" (vmapped
-    oracle twin) bit-identically, scores and ids AND telemetry."""
-    g = sg.graph
-    pins, weights, feats = _mk_batch(sg, 4)
-    cfg = _cfg(backend="xla")
-    key = jax.random.key(9)
-    outx = service.serve_batch(
-        g, pins, weights, feats, key, cfg, backend="xla", with_stats=True
-    )
-    outp = service.serve_batch(
-        g, pins, weights, feats, key, cfg, backend="pallas", with_stats=True
-    )
-    for a, b, name in zip(outx, outp, ("scores", "ids", "steps", "n_high")):
+def _oracle_serve(graph, pins, weights, feats, key, cfg, step_budgets):
+    """``serve_batch``'s answers from the vmapped per-query engine, called
+    directly: no route through ``serve_batch``'s engine choice."""
+    keys = jax.random.split(key, pins.shape[0])
+    cfg = dataclasses.replace(cfg, backend="xla")
+    if step_budgets is None:
+        return jax.vmap(
+            lambda qp, qw, uf, k: walk_lib.recommend_with_stats(
+                graph, qp, qw, uf, k, cfg
+            )
+        )(pins, weights, feats, keys)
+    return jax.vmap(
+        lambda qp, qw, uf, k, sb: walk_lib.recommend_with_stats(
+            graph, qp, qw, uf, k, cfg, step_budget=sb
+        )
+    )(pins, weights, feats, keys, step_budgets)
+
+
+def _assert_served_equal(got, want):
+    for a, b, name in zip(got, want, ("scores", "ids", "steps", "n_high")):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b), err_msg=name
         )
-    assert outp[0].shape == (4, cfg.top_k)
-    assert outp[2].shape == (4, 2)
+
+
+# ragged per-lane Eq. 2 budgets (cfg.n_steps is 1536); a batch takes the
+# first ``batch`` of them
+_RAGGED_BUDGETS = (700, 1536, 300, 1100)
+
+
+@pytest.mark.parametrize("budgeted", [False, True])
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_serve_batch_routes_pallas_through_batched_engine(
+    sg, backend, batch, budgeted
+):
+    """serve_batch on either backend == the vmapped per-query oracle,
+    called directly, bit-identically: scores and ids AND telemetry, with
+    the static Eq. 2 budget and with ragged per-lane budgets."""
+    g = sg.graph
+    pins, weights, feats = _mk_batch(sg, batch)
+    cfg = _cfg(backend="xla")
+    key = jax.random.key(9)
+    budgets = (
+        jnp.asarray(_RAGGED_BUDGETS[:batch], jnp.int32) if budgeted else None
+    )
+    out = service.serve_batch(
+        g, pins, weights, feats, key, cfg, backend=backend,
+        with_stats=True, step_budgets=budgets,
+    )
+    _assert_served_equal(
+        out, _oracle_serve(g, pins, weights, feats, key, cfg, budgets)
+    )
+    assert out[0].shape == (batch, cfg.top_k)
+    assert out[2].shape == (batch, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -237,22 +284,140 @@ def test_batched_engine_fits_envelope():
     assert not walk_lib.batched_engine_fits(64, 4, 1_000, 10_000_000, True)
 
 
-def test_serve_batch_falls_back_to_vmapped_past_envelope(sg, monkeypatch):
-    """Past the batched envelope, serve_batch must keep serving (vmapped
-    grids, batch-replicated) rather than raising where it used to work."""
+def _count_loops(serve, n_bins):
+    """``(while eqn, shapes of its int32 carries of n_bins elements)`` for
+    every while loop of ``serve``'s jaxpr that carries count buffers."""
+    jaxpr = jax.make_jaxpr(serve)(jax.random.key(0))
+    loops = []
+    for e in iter_eqns(jaxpr.jaxpr):
+        if e.primitive.name != "while":
+            continue
+        carries = [
+            tuple(v.aval.shape) for v in e.outvars
+            if v.aval.dtype == jnp.int32 and v.aval.size == n_bins
+        ]
+        if carries:
+            loops.append((e, carries))
+    return loops
+
+
+def _carry_selects(serve, shape):
+    """The ``stablehlo.select`` ops of ``serve``'s lowering that produce an
+    int32 array of ``shape``.  vmap turns a per-query while predicate into
+    a batched one, and the lowered body then selects every carry against
+    its old value each iteration; the jaxpr shows only the batched
+    predicate."""
+    dims = "x".join(str(d) for d in shape)
+    text = jax.jit(serve).lower(jax.random.key(0)).as_text()
+    return re.findall(
+        rf"stablehlo\.select .*tensor<{dims}xi32>$", text, re.MULTILINE
+    )
+
+
+@pytest.mark.parametrize("budgeted", [False, True])
+def test_xla_serve_step_carries_one_flat_count_buffer(sg, budgeted):
+    """The xla serve step runs the batch-native loop: exactly one while
+    loop carries counts, as ONE flat (batch * n_slots * n_pins,) int32
+    buffer, and its body never selects that carry against its old value
+    (the select that forces a copy of the table each chunk)."""
     g = sg.graph
-    batch = 4
-    pins, weights, feats = _mk_batch(sg, batch)
+    batch, n_slots = 4, 2
+    n_bins = batch * n_slots * g.n_pins
+    pins, weights, feats = _mk_batch(sg, batch, n_slots)
+    cfg = _cfg(backend="xla")
+    budgets = (
+        jnp.asarray(_RAGGED_BUDGETS[:batch], jnp.int32) if budgeted else None
+    )
+
+    def serve(key):
+        return service.serve_batch(g, pins, weights, feats, key, cfg,
+                                   step_budgets=budgets, with_stats=True)
+
+    loops = _count_loops(serve, n_bins)
+    assert len(loops) == 1, [c for _, c in loops]
+    loop, carries = loops[0]
+    assert carries == [(n_bins,)], carries
+    assert loop.params["cond_jaxpr"].out_avals[0].shape == ()
+    assert _carry_selects(serve, (n_bins,)) == []
+
+
+def test_serve_batch_falls_back_to_vmapped_past_envelope(sg, monkeypatch):
+    """Past the batched envelope, serve_batch must keep serving rather than
+    raising where it used to work: on pallas with vmapped grids,
+    batch-replicated; on xla with the vmapped (batch, n_slots * n_pins)
+    count carry, which vmap's batched predicate selects each chunk — and
+    on both with the oracle's answers."""
+    g = sg.graph
+    batch, n_slots = 4, 2
+    pins, weights, feats = _mk_batch(sg, batch, n_slots)
     cfg = _cfg()
     monkeypatch.setattr(walk_lib, "batched_engine_fits",
                         lambda *a, **k: False)
 
-    def serve(key):
+    def serve(key, backend="pallas"):
         return service.serve_batch(g, pins, weights, feats, key, cfg,
-                                   backend="pallas")
+                                   backend=backend, with_stats=True)
 
     grids = pallas_grids(jax.make_jaxpr(serve)(jax.random.key(0)))
     assert all(grid[0] == batch for grid in grids), grids
+
+    def serve_xla(key):
+        return serve(key, backend="xla")
+
+    n_bins = batch * n_slots * g.n_pins
+    carry = (batch, n_slots * g.n_pins)
+    loops = _count_loops(serve_xla, n_bins)
+    assert [c for _, c in loops] == [[carry]], loops
+    assert loops[0][0].params["cond_jaxpr"].out_avals[0].shape == (batch,)
+    assert len(_carry_selects(serve_xla, carry)) == 1
+    key = jax.random.key(4)
+    _assert_served_equal(
+        serve_xla(key),
+        _oracle_serve(g, pins, weights, feats, key, cfg, None),
+    )
+
+
+def test_walk_engine_chooses_by_shape(sg):
+    """``service.walk_engine``: the pod-sharded engine for a ShardedGraph,
+    the batch-native one while its bins fit int32, vmapped past that —
+    on either backend, since the backend only picks hop and count
+    implementations."""
+    g = sg.graph
+    for backend in ("xla", "pallas"):
+        cfg = _cfg(backend=backend)
+        assert service.walk_engine(g, 8, 8, cfg) == "batched"
+        assert service.walk_engine(
+            dist_lib.shard_graph(g, 2), 8, 8, cfg) == "sharded"
+        # the benchmark's buckets at 10M pins: 640M and 80M bins
+        big = types.SimpleNamespace(n_pins=10_000_000, n_boards=5_000_000)
+        assert service.walk_engine(big, 8, 8, cfg) == "batched"
+        assert service.walk_engine(big, 8, 1, cfg) == "batched"
+        # 64 queries x 4 slots x 10M pins = 2.56e9 bins: past int32
+        assert service.walk_engine(big, 64, 4, cfg) == "vmapped"
+        # boards widen the bin space only when they are counted
+        wide = types.SimpleNamespace(n_pins=1_000, n_boards=10_000_000)
+        assert service.walk_engine(wide, 64, 4, cfg) == "batched"
+        assert service.walk_engine(
+            wide, 64, 4, dataclasses.replace(cfg, count_boards=True)
+        ) == "vmapped"
+
+
+@pytest.mark.parametrize("fits", [True, False])
+def test_server_counts_batch_native_batches(sg, monkeypatch, fits):
+    """``ServerStats.batches_batch_native`` counts the batches dispatched
+    on the batch-native engine: all of them for a fitting bucket, none
+    when the envelope predicate says no (the vmapped fallback)."""
+    if not fits:
+        monkeypatch.setattr(walk_lib, "batched_engine_fits",
+                            lambda *a, **k: False)
+    server = PixieServer(sg.graph, _cfg(backend="xla"), batch_size=4,
+                         n_slots=2)
+    qs = top_degree_pins(sg, 6)
+    for i in range(6):  # 6 requests -> 2 batches (one padded)
+        server.submit([int(qs[i])], [1.0], user_feat=0)
+    assert len(server.flush()) == 6
+    assert server.stats.batches == 2
+    assert server.stats.batches_batch_native == (2 if fits else 0)
 
 
 def test_batched_engine_validates_inputs(sg):

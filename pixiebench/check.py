@@ -6,7 +6,11 @@ reference (``reference.py``) from the request as the client sent it: its
 slots, its language, its request id and the server's seed.  A served answer
 matches when its top-k list lies within ``gap_tol`` of the reference's
 (``reference.answer_gap``: scores pin by pin and rank by rank, relative to
-the best score).  The numbers compared, each with its limit from the
+the best score).  On a configuration with a ``ranker`` the answer is stage
+2's: it matches when it lies within ``rank_gap_tol`` of the reference's
+ranking of the reference's own candidates (``reference.rank`` and
+``rank_gap``: head scores pin by pin and rank by rank, relative to the
+largest).  The numbers compared, each with its limit from the
 configuration's ``check`` section:
 
   * ``mismatch_share``: share of the sample whose answer does not match;
@@ -17,7 +21,7 @@ configuration's ``check`` section:
 
 from __future__ import annotations
 
-from typing import Dict, List, NamedTuple, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -49,22 +53,32 @@ def sample(widths: np.ndarray, answered: np.ndarray, seed: int, k: int,
 
 
 def gaps(hg: reference.HostGraph, served: Sequence[Served], server_seed: int,
-         walk: Dict, chunk_steps: int) -> np.ndarray:
-    """``answer_gap`` of every served answer against the reference."""
+         walk: Dict, chunk_steps: int,
+         stage2: Optional[reference.Stage2] = None) -> np.ndarray:
+    """``answer_gap`` of every served answer against the reference, or
+    with ``stage2`` the ``rank_gap`` of every ranked answer."""
     out = []
     for s in served:
         ref = reference.recommend(
             hg, s.pins, s.weights, s.feat,
             reference.request_key(server_seed, s.req_id), walk, chunk_steps,
         )
-        out.append(reference.answer_gap(s.scores, s.ids, ref))
+        if stage2 is None:
+            out.append(reference.answer_gap(s.scores, s.ids, ref))
+        else:
+            out.append(reference.rank_gap(
+                s.scores, s.ids, reference.rank(hg, ref, stage2)))
     return np.asarray(out, np.float64)
 
 
-def judge(gap: np.ndarray, missing: int, check_cfg: Dict) -> Tuple[bool, Dict]:
-    """``(correct, numbers)``; each number is ``{"value", "limit"}``."""
+def judge(gap: np.ndarray, missing: int, check_cfg: Dict,
+          ranked: bool = False) -> Tuple[bool, Dict]:
+    """``(correct, numbers)``; each number is ``{"value", "limit"}``.  A
+    ranked answer's gaps are held to ``rank_gap_tol``, a walk's to
+    ``gap_tol``."""
     limits = check_cfg["limits"]
-    share = float(np.mean(gap > check_cfg["gap_tol"])) if gap.size else 1.0
+    tol = check_cfg["rank_gap_tol" if ranked else "gap_tol"]
+    share = float(np.mean(gap > tol)) if gap.size else 1.0
     numbers = {
         "mismatch_share": {"value": share, "limit": limits["mismatch_share"]},
         "missing": {"value": int(missing), "limit": limits["missing"]},

@@ -15,7 +15,11 @@ and no dense count table, and imports nothing of the program under test:
     reached ``n_v`` visits or its steps reached ``N_q``;
   * the query pin is removed from its own slot's counts, slots combine by
     Eq. 3, ``V[p] = (sum_q sqrt(V_q[p]))**2``, and the top ``top_k`` pins
-    are the answer.
+    are the answer;
+  * stage 2, where the configuration states a ranker (``rank``): the
+    answer's top ``n_candidates`` pins, each scored by the cell's head from
+    its own row, a fixed 2-hop fan of its neighbours and the pool of the
+    candidates themselves; the top ``final_k`` are the answer.
 
 Random bits: a served request draws its walk from its own stream, the
 request key ``fold_in(key(server_seed), request_id)``; step ``s`` takes
@@ -24,7 +28,8 @@ request key ``fold_in(key(server_seed), request_id)``; step ``s`` takes
 bits with JAX's public PRNG and does everything else in NumPy, so a served
 answer and the reference agree exactly when the walk, the counting, the
 stop rule and the combine are right.  Float32 arithmetic mirrors the
-program's precision for the budget split (Eq. 1-2); the combine is float64.
+program's precision for the budget split (Eq. 1-2); the combine is float64,
+with a float32 twin that orders the candidates handed to stage 2.
 """
 
 from __future__ import annotations
@@ -151,6 +156,7 @@ class Answer(NamedTuple):
     scores: np.ndarray     # Eq. 3 scores (float64) of ``ids``
     steps_taken: np.ndarray
     n_high: np.ndarray
+    scores32: Optional[np.ndarray] = None   # Eq. 3 in float32, slot order
 
 
 def recommend(hg: HostGraph, pins: np.ndarray, weights: np.ndarray,
@@ -225,9 +231,14 @@ def recommend(hg: HostGraph, pins: np.ndarray, weights: np.ndarray,
     ids, inv = np.unique(pin, return_inverse=True)
     root = np.zeros(ids.shape[0], np.float64)
     np.add.at(root, inv, np.sqrt(cnt.astype(np.float64)))
+    # the program sums float32 roots over the slot axis; np.add.at adds in
+    # the keys' order, which is slot order for each pin
+    root32 = np.zeros(ids.shape[0], np.float32)
+    np.add.at(root32, inv, np.sqrt(cnt.astype(np.float32)))
     return Answer(ids=ids, scores=root * root,
                   steps_taken=steps_taken.astype(np.int32),
-                  n_high=(high - q_reached).astype(np.int32))
+                  n_high=(high - q_reached).astype(np.int32),
+                  scores32=root32 * root32)
 
 
 def answer_gap(served_scores: np.ndarray, served_ids: np.ndarray,
@@ -255,4 +266,142 @@ def answer_gap(served_scores: np.ndarray, served_ids: np.ndarray,
     scale = max(float(best[0]) if k else 1.0, 1.0)
     by_pin = np.max(np.abs(s - ref_of_served)) if k else 0.0
     by_rank = np.max(np.abs(np.sort(s)[::-1] - best)) if k else 0.0
+    return float(max(by_pin, by_rank) / scale)
+
+
+# -- stage 2 ------------------------------------------------------------------
+
+FAN_STRIDE, FAN_OFFSET = 31, 7     # neighbour j's pick on its board
+
+
+class Stage2(NamedTuple):
+    """Host copy of the ranker one cell serves: the item table, the head
+    its mix names, and the configuration's ``ranker`` sizes."""
+
+    items: np.ndarray      # (n_pins, d)
+    w_self: np.ndarray     # (d, d)
+    w_neigh: np.ndarray    # (d, d)
+    w_query: np.ndarray    # (d, d)
+    b: np.ndarray          # (d,)
+    n_candidates: int
+    n_neighbors: int
+    final_k: int
+
+
+def stage2(params: Dict, ranker: Dict, head: str) -> Stage2:
+    """The cell's head out of the host parameters (heads stacked on a
+    leading axis in the order of the configuration's ``scenarios``)."""
+    h = list(ranker["scenarios"]).index(head)
+    heads = params["heads"]
+    pick = lambda k: np.asarray(heads[k][h], np.float64)
+    return Stage2(np.asarray(params["items"]), pick("w_self"),
+                  pick("w_neigh"), pick("w_query"), pick("b"),
+                  int(ranker["n_candidates"]), int(ranker["n_neighbors"]),
+                  int(ranker["final_k"]))
+
+
+class Ranked(NamedTuple):
+    cand_ids: np.ndarray     # stage-1 candidates, best first
+    cand_scores: np.ndarray  # their head scores, -inf where not valid
+    ids: np.ndarray          # the final_k answer, -1 past the valid ones
+    scores: np.ndarray       # its head scores, -inf past the valid ones
+
+
+def fan(hg: HostGraph, cand: np.ndarray, valid: np.ndarray,
+        n_neighbors: int) -> tuple:
+    """Candidate c's neighbour j is pin ``(j*31 + 7) % deg(b)`` of board
+    ``b``, the ``j % deg(c)``-th of c's boards, with weight ``1 / (1 + j)``;
+    id -1 and weight 0 where the fan dead-ends (invalid candidate, isolated
+    pin, empty board)."""
+    safe = np.where(valid, cand, 0)
+    start = hg.p2b_off[safe]
+    deg = hg.p2b_off[safe + 1] - start
+    j = np.arange(n_neighbors)
+    board_ok = (deg > 0)[:, None]
+    at = np.where(board_ok, start[:, None] + j % np.maximum(deg, 1)[:, None],
+                  0)
+    board = np.where(board_ok, hg.p2b_tgt[at] - hg.n_pins, 0)
+    bstart = hg.b2p_off[board]
+    bdeg = hg.b2p_off[board + 1] - bstart
+    ok = valid[:, None] & board_ok & (bdeg > 0)
+    pick = np.where(ok, bstart + (j * FAN_STRIDE + FAN_OFFSET)
+                    % np.maximum(bdeg, 1), 0)
+    return (np.where(ok, hg.b2p_tgt[pick], -1),
+            ok / (1.0 + j))
+
+
+def mean_bag(items: np.ndarray, ids: np.ndarray, w: np.ndarray) -> np.ndarray:
+    """``sum w_i e_i / max(sum w_i, 1)`` over each row's ids (-1: none)."""
+    w = np.where(ids >= 0, w, 0.0)
+    rows = items[np.maximum(ids, 0)].astype(np.float64)
+    return (np.einsum("...l,...ld->...d", w, rows)
+            / np.maximum(w.sum(-1), 1.0)[..., None])
+
+
+def rank(hg: HostGraph, ans: Answer, st: Stage2,
+         head_dtype=None) -> Ranked:
+    """Stage 2 of one request in float64, from its stage-1 ``ans``.
+
+    Candidates: the top ``n_candidates`` pins by (float32 Eq. 3 score
+    descending, pin id ascending), valid where the score is above 0.  Each
+    is scored ``relu(e_c W_self + n_c W_neigh + b) . (q W_query) / sqrt(d)``,
+    where ``e_c`` is its row, ``n_c`` the mean of its fan's rows and ``q``
+    the mean of the valid candidates' rows weighted by ``sqrt(score)``.
+    The answer is the top ``final_k`` valid candidates, ties to the better
+    stage-1 place.  ``head_dtype`` rounds the inputs of the head's three
+    products to that type (the control: ``ml_dtypes.bfloat16``).
+    """
+    k = st.n_candidates
+    order = np.lexsort((ans.ids, -ans.scores32))[:k]
+    cand = np.zeros(k, np.int64)
+    score = np.zeros(k, np.float32)
+    cand[:order.size] = ans.ids[order]
+    score[:order.size] = ans.scores32[order]
+    valid = score > 0
+    nbr, w = fan(hg, cand, valid, st.n_neighbors)
+    neigh = mean_bag(st.items, nbr, w)
+    own = st.items[cand].astype(np.float64) * valid[:, None]
+    query = mean_bag(st.items, np.where(valid, cand, -1),
+                     np.sqrt(score.astype(np.float64)))
+    if head_dtype is None:
+        cast = lambda x: x
+    else:
+        cast = lambda x: np.asarray(x).astype(head_dtype).astype(np.float64)
+    h = np.maximum(cast(own) @ cast(st.w_self)
+                   + cast(neigh) @ cast(st.w_neigh) + st.b, 0.0)
+    qv = cast(query) @ cast(st.w_query)
+    head = cast(h) @ cast(qv) / np.sqrt(st.items.shape[1])
+    head = np.where(valid, head, -np.inf)
+    top = np.argsort(-head, kind="stable")[:st.final_k]
+    live = np.isfinite(head[top])
+    return Ranked(cand, head, np.where(live, cand[top], -1),
+                  np.where(live, head[top], -np.inf))
+
+
+def rank_gap(served_scores: np.ndarray, served_ids: np.ndarray,
+             ref: Ranked) -> float:
+    """How far a ranked answer lies from the reference's, as ``answer_gap``
+    reads a walk's: the larger of the widest gap between a served pin's head
+    score and the reference's score of that candidate, and the widest gap
+    rank by rank, over the largest of the reference's answer scores.  A
+    served pin that is no valid reference candidate, a repeated pin, or
+    another number of answers than the reference's reads as a gap of 1."""
+    i = np.asarray(served_ids, np.int64)
+    s = np.asarray(served_scores, np.float64)
+    n = int(np.sum(ref.ids >= 0))
+    live = i >= 0
+    if int(live.sum()) != n or np.unique(i[live]).size != n:
+        return 1.0
+    if n == 0:
+        return 0.0
+    i, s = i[live], s[live]
+    known = np.isfinite(ref.cand_scores)
+    where = {int(c): x for c, x in zip(ref.cand_ids[known],
+                                         ref.cand_scores[known])}
+    if any(int(p) not in where for p in i):
+        return 1.0
+    want = ref.scores[:n]
+    scale = float(np.max(np.abs(want))) or 1.0
+    by_pin = np.max(np.abs(s - np.array([where[int(p)] for p in i])))
+    by_rank = np.max(np.abs(np.sort(s)[::-1] - want))
     return float(max(by_pin, by_rank) / scale)

@@ -7,7 +7,8 @@ real shapes, the graph generator and the server's serving step (the jitted
 program ``PixieServer`` dispatches for its default bucket) against one chip
 of a described ``v5e:2x2`` topology, and prints each program's
 ``memory_analysis``.  It finds what the chip's compiler would refuse and
-whether the programs fit the chip's memory; it measures no time.
+whether the programs fit the chip's memory; it measures no time.  A ranked
+configuration's serving step is not compiled (see ``rehearse_cell``).
 """
 
 from __future__ import annotations
@@ -61,6 +62,11 @@ def rehearse_cell(cell, config, topo_name: str = "v5e:2x2") -> dict:
                 sds((nb, nl + 1), jnp.int32)),
         n_pins=n, n_boards=nb, max_pin_degree=spec.max_pin_degree,
     )
+    if "ranker" in config:
+        # the ranked program closes over the ranker's parameters, so it
+        # cannot be lowered from shapes alone
+        out["serve_step"] = "not compiled: a ranked step needs its parameters"
+        return out
     server = PixieServer(graph, WalkConfig(**config["walk"]),
                          n_slots=config["n_slots"])
     b, s = server.batch_size, config["n_slots"]

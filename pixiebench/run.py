@@ -5,20 +5,24 @@
 
 Set-up enables JAX's persistent compilation cache at ``<checkout>/.jax_cache``,
 refuses to go on without a TPU (or with fewer chips than the cell asks
-for), builds the configuration's graph on the device from the seed,
-constructs ``PixieServer`` with only what the configuration states (graph,
-walk settings, query width; batch size, buckets, ``max_wait_ms`` and the
-walk engine stay at the program's defaults) and warms up the serving
-shapes.  The window then drives the server open-loop for ``--seconds``:
-at each request's due time the client builds its query
-(``service.build_query`` for action histories), calls
-``submit(..., now=due)`` and ``pump(now=wall)``; whenever batches are in
-flight it collects them with ``harvest()``.  A request's latency runs from
-its due time to the return of the ``harvest`` that delivered it, so a stall
-counts against every request behind it.  After the window every request is
+for), builds the configuration's graph on the device from the seed (and,
+where the configuration has a ``ranker`` section, the stage-2 ranker's
+parameters: ``rankgen.py``), constructs ``PixieServer`` with only what the
+configuration states (graph, walk settings, query width, ranker; batch
+size, buckets, ``max_wait_ms`` and the walk engine stay at the program's
+defaults) and warms up the serving shapes.  The window then drives the
+server open-loop for ``--seconds``: at each request's due time the client
+builds its query (``service.build_query`` for action histories), calls
+``submit(..., now=due)`` (on a ranked configuration with ``scenario=`` the
+head the mix names) and ``pump(now=wall)``; whenever batches are in flight
+it collects them with ``harvest()``.  A request's latency runs from its due
+time to the return of the ``harvest`` that delivered it, so a stall counts
+against every request behind it.  After the window every request is
 drained; then the device's peak memory is read, the server is freed, and a
 sample of the answers is checked against the plain reference
-(``check.py``).
+(``check.py``).  With ``--trace 1`` the window is profiled, and the trace
+is reduced to device time per stage, the program's spans and its counters
+over the window (``stages.py``), which the per-layer readers read.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -38,7 +42,7 @@ import sys
 import tempfile
 import time
 from contextlib import nullcontext
-from typing import Dict, List
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -127,6 +131,7 @@ class Run:
     batches: int
     summary: object = None     # trace.Summary of a --trace 1 run
     trace_t0_ns: int = 0       # trace clock at the window's start
+    stages: object = None      # stages.Stages of a --trace 1 run
 
     def latency_ms(self) -> np.ndarray:
         lat = (self.done - self.due) * 1e3
@@ -158,11 +163,17 @@ def _sleep_until(t: float) -> None:
         pass
 
 
-def drive(server, reqs, n_slots: int, t0: float, span) -> Dict:
+def _head(scenario: Optional[int]) -> Dict:
+    """``submit``'s head argument: none on a retrieval-only server."""
+    return {} if scenario is None else {"scenario": scenario}
+
+
+def drive(c: "Cell", reqs, t0: float, span) -> Dict:
     """The open loop: submit each request at its due time, pump, harvest.
     Returns the per-request records (seconds from ``t0``)."""
     from repro.core import service
 
+    server, n_slots, head = c.server, c.config["n_slots"], _head(c.scenario)
     n = len(reqs)
     due_abs = t0 + np.array([r.due_s for r in reqs])
     rec = {
@@ -179,7 +190,7 @@ def drive(server, reqs, n_slots: int, t0: float, span) -> Dict:
                 pins, weights = _query(reqs[i], n_slots, service)
             with span("pb.submit"):
                 rid = server.submit(pins, weights, user_feat=reqs[i].feat,
-                                    now=float(due_abs[i]))
+                                    now=float(due_abs[i]), **head)
             rec["submitted"][i] = time.perf_counter() - t0
             if rid is None:
                 rec["failed"][i] = True
@@ -217,7 +228,8 @@ def drive(server, reqs, n_slots: int, t0: float, span) -> Dict:
     return rec
 
 
-def warm_up(server, reqs, n_slots: int, batch_size: int) -> None:
+def warm_up(server, reqs, n_slots: int, batch_size: int,
+            scenario: Optional[int] = None) -> None:
     """Serve full and partial batches through the window's own calls."""
     from repro.core import service
 
@@ -225,7 +237,7 @@ def warm_up(server, reqs, n_slots: int, batch_size: int) -> None:
     for size in [batch_size] * (WARMUP_BATCHES - 1) + [1]:
         for r in reqs[at:at + size]:
             pins, weights = _query(r, n_slots, service)
-            server.submit(pins, weights, user_feat=r.feat)
+            server.submit(pins, weights, user_feat=r.feat, **_head(scenario))
         at += size
         server.pump(now=time.perf_counter() + 1.0)
         for q in server.harvest():
@@ -246,15 +258,32 @@ class Cell:
     server: object
     server_seed: int
     offsets: np.ndarray
+    scenario: Optional[int] = None   # the mix's head, on a ranked server
+
+
+def head_of(config: Dict, traffic: Dict) -> Optional[str]:
+    """The ranker head the mix names, or None for a retrieval-only cell.
+    A configuration with a ``ranker`` section serves only a mix that names
+    its ``scenario``, and a mix that names one only a ranked configuration:
+    no head is ever taken by default."""
+    head = traffic.get("scenario")
+    if "ranker" in config and head is None:
+        raise ValueError("the configuration states a ranker, but the "
+                         "traffic mix names no scenario (ranker head)")
+    if "ranker" not in config and head is not None:
+        raise ValueError(f"the traffic mix names scenario {head!r}, but the "
+                         "configuration states no ranker")
+    return head
 
 
 def set_up(cell: Dict, config: Dict, traffic: Dict, seed: int) -> Cell:
-    """Everything before the window: chip check, cache, graph, server,
-    warm-up."""
-    from pixiebench import graphgen, loadgen
+    """Everything before the window: chip check, cache, graph, ranker
+    parameters, server, warm-up."""
+    from pixiebench import graphgen, loadgen, rankgen
     from repro.core.walk import WalkConfig
     from repro.serving.server import PixieServer
 
+    head = head_of(config, traffic)
     devices = require_tpu(cell["chips"])
     peaks_for(devices[0].device_kind)
     enable_compile_cache()
@@ -262,27 +291,52 @@ def set_up(cell: Dict, config: Dict, traffic: Dict, seed: int) -> Cell:
     graph, _, gstats = graphgen.generate(spec, seed)
     log(f"graph: {json.dumps(gstats)}")
     server_seed = seed % (2**31 - 1)
+    kw = {}
+    if head is not None:
+        from repro.serving import ranker as ranker_lib
+
+        rcfg = ranker_lib.RankerConfig(
+            n_items=spec.n_pins,
+            **dict(config["ranker"],
+                   scenarios=tuple(config["ranker"]["scenarios"])))
+        kw["ranker"] = ranker_lib.RankRequest(
+            rankgen.ranker_params(server_seed, spec.n_pins,
+                                  config["ranker"]), rcfg)
     server = PixieServer(graph, WalkConfig(**config["walk"]),
-                         n_slots=config["n_slots"], seed=server_seed)
+                         n_slots=config["n_slots"], seed=server_seed, **kw)
+    scenario = None if head is None else server.ranker.cfg.scenario_id(head)
     offsets = np.asarray(graph.p2b.offsets)
     warm = loadgen.warmup_requests(
         traffic, seed, WARMUP_BATCHES * server.batch_size, offsets)
-    warm_up(server, warm, config["n_slots"], server.batch_size)
+    warm_up(server, warm, config["n_slots"], server.batch_size, scenario)
     return Cell(cell, config, traffic, seed, devices, spec, graph, server,
-                server_seed, offsets)
+                server_seed, offsets, scenario)
 
 
-def measure(c: Cell, reqs, trace: bool):
-    """Drive one window; returns ``(records, trace summary or None,
-    compilations in the window)``."""
+class Window(NamedTuple):
+    """What ``measure`` returns; ``summary`` and ``stages`` only when
+    traced."""
+
+    rec: Dict
+    summary: object            # trace.Summary
+    stages: object             # stages.Stages
+    compiles: int              # compilations inside the window
+
+
+def measure(c: Cell, reqs, trace: bool) -> Window:
+    """Drive one window.  Traced, the profile is reduced to a
+    ``trace.Summary`` and the stage split (``stages.reduce``, with the
+    server's counters over the window) of the cell's own devices."""
     import jax
 
+    from pixiebench import stages
     from pixiebench import trace as trace_lib
 
     counter = CompileCounter()
     log_dir = None
     if trace:
         log_dir = tempfile.mkdtemp(prefix="pixiebench-trace-")
+        before = stages.counters(c.server)
         trace_lib.start(log_dir)
         span = jax.profiler.TraceAnnotation
     else:
@@ -293,32 +347,52 @@ def measure(c: Cell, reqs, trace: bool):
         counter.on = True
         with span("pb.window"):
             t0 = time.perf_counter()
-            rec = drive(c.server, reqs, c.config["n_slots"], t0, span)
+            rec = drive(c, reqs, t0, span)
         counter.on = False
     finally:
         gc.enable()
-    summary = None
+    summary = st = None
     if trace:
         jax.profiler.stop_trace()
+        after = stages.counters(c.server)
         try:
-            devices_ev, host_ev = trace_lib.load(
-                trace_lib.find_xplane(log_dir))
+            path = trace_lib.find_xplane(log_dir)
+            devices_ev, host_ev = trace_lib.load(path)
+            ops, spans = stages.load(path)
         finally:
             shutil.rmtree(log_dir, ignore_errors=True)
+        mine = lambda plane: int(plane.rsplit(":", 1)[1]) < len(c.devices)
         summary = trace_lib.reduce(
-            {k: v for k, v in devices_ev.items()
-             if int(k.rsplit(":", 1)[1]) < len(c.devices)}, host_ev)
+            {k: v for k, v in devices_ev.items() if mine(k)}, host_ev)
+        st = stages.reduce({k: v for k, v in ops.items() if mine(k)},
+                           summary, spans,
+                           {k: after[k] - before[k] for k in after})
     late = (rec["submitted"] - np.array([r.due_s for r in reqs])) * 1e3
     log(f"window: requests={len(reqs)} batches={rec['batches']} "
         f"compiles_in_window={counter.count} generator_late_ms: "
         f"p50={np.nanpercentile(late, 50):.3f} "
         f"p95={np.nanpercentile(late, 95):.3f} max={np.nanmax(late):.3f}")
-    return rec, summary, counter.count
+    return Window(rec, summary, st, counter.count)
+
+
+def window_run(reqs, w: Window, seconds: float, setup_s: float) -> Run:
+    """What the metric readers read of one window."""
+    return Run(seconds=seconds, setup_s=setup_s,
+               due=np.array([r.due_s for r in reqs]),
+               submitted=w.rec["submitted"], done=w.rec["done"],
+               wait_ms=w.rec["wait_ms"], failed=w.rec["failed"],
+               pumps=w.rec["pumps"], batches=w.rec["batches"],
+               summary=w.summary,
+               trace_t0_ns=w.summary.window[0] if w.summary else 0,
+               stages=w.stages)
 
 
 def release_and_sample(c: Cell, rec: Dict, reqs) -> tuple:
-    """Copy the graph to the host, free the program's state, and pick the
-    answers to check.  Returns ``(host graph, [check.Served])``."""
+    """Copy the graph (and a ranked cell's ranker: ``reference.Stage2``) to
+    the host, free the program's state, and pick the answers to check.
+    Returns ``(host graph, [check.Served], Stage2 or None)``."""
+    import jax
+
     from pixiebench import check, reference
 
     g = c.graph
@@ -329,6 +403,10 @@ def release_and_sample(c: Cell, rec: Dict, reqs) -> tuple:
         "b2p_feat_bounds": g.b2p.feat_bounds,
     }
     hg = reference.host_graph(arrays, c.spec.n_pins, c.spec.max_pin_degree)
+    st2 = None
+    if c.scenario is not None:
+        st2 = reference.stage2(jax.device_get(c.server.ranker.params),
+                               c.config["ranker"], c.traffic["scenario"])
     del g, arrays
     c.graph = c.server = None
     gc.collect()
@@ -346,7 +424,7 @@ def release_and_sample(c: Cell, rec: Dict, reqs) -> tuple:
         qw[:len(pins)] = weights
         scores, ids = rec["answers"][j]
         served.append(check.Served(rid, qp, qw, reqs[j].feat, scores, ids))
-    return hg, served
+    return hg, served, st2
 
 
 def free_and_check(c: Cell, rec: Dict, reqs) -> tuple:
@@ -355,33 +433,29 @@ def free_and_check(c: Cell, rec: Dict, reqs) -> tuple:
     from pixiebench import check
 
     chunk_steps = c.server.cfg.chunk_steps
-    hg, served = release_and_sample(c, rec, reqs)
+    hg, served, st2 = release_and_sample(c, rec, reqs)
     t_check = time.perf_counter()
     gaps = check.gaps(hg, served, c.server_seed, c.config["walk"],
-                      chunk_steps)
+                      chunk_steps, st2)
     widest = float(gaps.max()) if gaps.size else float("nan")
-    log(f"check: {len(served)} answers in "
+    log(f"check: {len(served)} {'ranked ' if st2 else ''}answers in "
         f"{time.perf_counter() - t_check:.1f} s; widest gap {widest:.3g}; "
         f"chunk_steps={chunk_steps}")
-    return check.judge(gaps, int(rec["failed"].sum()), c.config["check"])
+    return check.judge(gaps, int(rec["failed"].sum()), c.config["check"],
+                       ranked=st2 is not None)
 
 
 def run_cell(cell: Dict, config: Dict, traffic: Dict, seed: int,
              seconds: float, trace: bool, metrics: List[Dict]) -> Dict:
     """Set up, drive and check one run; returns the result object."""
-    from pixiebench import loadgen
-    from pixiebench import trace as trace_lib
+    from pixiebench import loadgen, stages
 
     c = set_up(cell, config, traffic, seed)
     reqs = loadgen.schedule(traffic, seed, seconds, c.offsets)
     setup_s = process_age_s()
-    rec, summary, _ = measure(c, reqs, trace)
-    run = Run(seconds=seconds, setup_s=setup_s,
-              due=np.array([r.due_s for r in reqs]),
-              submitted=rec["submitted"], done=rec["done"],
-              wait_ms=rec["wait_ms"], failed=rec["failed"],
-              pumps=rec["pumps"], batches=rec["batches"], summary=summary,
-              trace_t0_ns=summary.window[0] if summary else 0)
+    w = measure(c, reqs, trace)
+    rec, summary = w.rec, w.summary
+    run = window_run(reqs, w, seconds, setup_s)
     values = {}
     for m in metrics:
         v = registry.metric_reader(m["name"])(run)
@@ -404,7 +478,7 @@ def run_cell(cell: Dict, config: Dict, traffic: Dict, seed: int,
         "device": device,
     }
     if summary is not None:
-        out["breakdown"] = trace_lib.breakdown(summary)
+        out["breakdown"] = stages.breakdown(summary, w.stages)
     out["check"] = numbers
     return out
 

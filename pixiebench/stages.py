@@ -20,41 +20,37 @@ and module events; this module adds, from the same ``.xplane.pb``:
 * idle gaps named by the span (``pb.*`` or ``pixie.*``) that is the
   innermost one over most of the gap;
 * the counters ``ServerStats`` keeps (batch fill, walk steps taken and
-  budgeted) over the window, from snapshots before and after it.
+  budgeted, batches on the batch-native walk loop) over the window, from
+  snapshots before and after it.
 
-The metric readers ``walk_hop_ms``, ``count_ms``, ``topk_ms``,
-``dispatch_form_ms``, ``batch_fill`` and ``steps_taken_share`` read a
-``Stages`` from ``run.stages`` and return None where a run has none.
+``run.measure`` fills ``run.Run.stages`` in every traced window.  The metric
+readers ``walk_hop_ms``, ``count_ms``, ``topk_ms``, ``rank_ms``,
+``dispatch_form_ms``, ``batch_fill`` and ``steps_taken_share`` read it and
+return None where a run has none.
 
     python3 -m pixiebench.stages --workload <cell> --seed <n> --seconds <s>
 
-sets the cell up as ``run.py`` does, drives one traced window and prints one
-JSON object: those six metrics, the cell's per-layer metrics and
-``completed_rps`` of the traced window, the breakdown with stages, and the
-counters.  It does not check the answers; ``run.py`` does.
+sets the cell up as ``run.py`` does, drives one traced window
+(``run.measure``) and prints one JSON object: the stage readers' metrics,
+the cell's per-layer metrics and ``completed_rps`` of the traced window,
+the breakdown with stages, and the counters.  It does not check the
+answers; ``run.py`` does.
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import gc
 import json
 import re
-import shutil
 import sys
-import tempfile
-import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
-
-import numpy as np
 
 from pixiebench import registry, run, trace
 
 SPAN_PREFIX = "pixie."
 UNSCOPED = "unscoped"
 COUNTERS = ("lanes_filled", "lanes_dispatched", "steps_taken",
-            "steps_budgeted")
+            "steps_budgeted", "batches_batch_native")
 _SCOPE = re.compile(r"pixie\.[A-Za-z0-9_.]*[A-Za-z0-9_]")
 
 Op = Tuple[str, int, int, Optional[str]]        # (name, start, dur, stage)
@@ -294,8 +290,8 @@ def breakdown(summary: trace.Summary, st: Stages, top: int = 10) -> Dict:
              ns / 1e9] for name, ns in ops],
         "idle_gaps": [[name_gap(g, spans), (g[1] - g[0]) / 1e9]
                       for g in idle],
-        "device_stages": [[k, v / 1e9] for k, v in
-                          sorted(st.device_ns.items(), key=lambda kv: -kv[1])],
+        "device_stages": [[k, v / 1e9] for k, v in sorted(
+            st.device_ns.items(), key=lambda kv: -kv[1])[:top]],
     }
 
 
@@ -332,51 +328,13 @@ def counter_share(run_, part: str, whole: str) -> Optional[float]:
 
 # -- the command ---------------------------------------------------------------
 
-STAGE_METRICS = ("walk_hop_ms", "count_ms", "topk_ms", "dispatch_form_ms",
-               "batch_fill", "steps_taken_share")
-
-
-@dataclasses.dataclass
-class StagedRun(run.Run):
-    stages: Optional[Stages] = None
+STAGE_METRICS = ("walk_hop_ms", "count_ms", "topk_ms", "rank_ms",
+                 "dispatch_form_ms", "batch_fill", "steps_taken_share")
 
 
 def counters(server) -> Dict[str, int]:
     """The ``ServerStats`` counters the readers use."""
     return {k: getattr(server.stats, k) for k in COUNTERS}
-
-
-def measure(c: run.Cell, reqs):
-    """Drive one traced window; returns ``(records, trace.Summary,
-    Stages)``."""
-    import jax
-
-    span = jax.profiler.TraceAnnotation
-    log_dir = tempfile.mkdtemp(prefix="pixiebench-stages-")
-    try:
-        before = counters(c.server)
-        trace.start(log_dir)
-        gc.collect()
-        gc.disable()
-        try:
-            with span("pb.window"):
-                t0 = time.perf_counter()
-                rec = run.drive(c.server, reqs, c.config["n_slots"], t0, span)
-        finally:
-            gc.enable()
-        jax.profiler.stop_trace()
-        after = counters(c.server)
-        path = trace.find_xplane(log_dir)
-        devices_ev, host_ev = trace.load(path)
-        ops, spans = load(path)
-    finally:
-        shutil.rmtree(log_dir, ignore_errors=True)
-    mine = lambda plane: int(plane.rsplit(":", 1)[1]) < len(c.devices)
-    summary = trace.reduce(
-        {k: v for k, v in devices_ev.items() if mine(k)}, host_ev)
-    delta = {k: after[k] - before[k] for k in after}
-    return rec, summary, reduce({k: v for k, v in ops.items() if mine(k)},
-                                summary, spans, delta)
 
 
 def run_stages(cell: Dict, config: Dict, traffic: Dict, seed: int,
@@ -388,13 +346,8 @@ def run_stages(cell: Dict, config: Dict, traffic: Dict, seed: int,
     c = run.set_up(cell, config, traffic, seed)
     reqs = loadgen.schedule(traffic, seed, seconds, c.offsets)
     setup_s = run.process_age_s()
-    rec, summary, st = measure(c, reqs)
-    r = StagedRun(seconds=seconds, setup_s=setup_s,
-                  due=np.array([q.due_s for q in reqs]),
-                  submitted=rec["submitted"], done=rec["done"],
-                  wait_ms=rec["wait_ms"], failed=rec["failed"],
-                  pumps=rec["pumps"], batches=rec["batches"],
-                  summary=summary, trace_t0_ns=summary.window[0], stages=st)
+    w = run.measure(c, reqs, trace=True)
+    r = run.window_run(reqs, w, seconds, setup_s)
     names = ["completed_rps"] + [m["name"] for m in metrics] + list(
         STAGE_METRICS)
     values = {}
@@ -403,11 +356,12 @@ def run_stages(cell: Dict, config: Dict, traffic: Dict, seed: int,
         if v is not None:
             values[name] = v
     return {
-        "attempted": len(reqs), "failed": int(rec["failed"].sum()),
-        "metrics": values, "counters": st.counters,
+        "attempted": len(reqs), "failed": int(w.rec["failed"].sum()),
+        "metrics": values, "counters": w.stages.counters,
         "device": {"kind": c.devices[0].device_kind,
-                   "busy_s": summary.busy_s, "window_s": summary.window_s},
-        "breakdown": breakdown(summary, st),
+                   "busy_s": w.summary.busy_s,
+                   "window_s": w.summary.window_s},
+        "breakdown": breakdown(w.summary, w.stages),
     }
 
 

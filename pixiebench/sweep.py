@@ -70,8 +70,7 @@ def dump_trace(c, reqs, out_dir: str) -> None:
     trace_lib.start(log_dir)
     with jax.profiler.TraceAnnotation("pb.window"):
         t0 = run.time.perf_counter()
-        rec = run.drive(c.server, reqs, c.config["n_slots"], t0,
-                        jax.profiler.TraceAnnotation)
+        rec = run.drive(c, reqs, t0, jax.profiler.TraceAnnotation)
     jax.profiler.stop_trace()
     path = trace_lib.find_xplane(log_dir)
     os.makedirs(out_dir, exist_ok=True)
@@ -124,9 +123,10 @@ def main(argv=None) -> int:
     def window(rate: float, i: int):
         reqs = loadgen.schedule(dict(traffic, rate_rps=rate), args.seed + i,
                                 args.seconds, c.offsets)
-        rec, _, compiles = run.measure(c, reqs, trace=False)
+        w = run.measure(c, reqs, trace=False)
+        rec = w.rec
         st = window_stats(rate, args.seconds, reqs, rec)
-        st["compiles_in_window"] = compiles
+        st["compiles_in_window"] = w.compiles
         st["sustained"] = sustained(st)
         print(json.dumps(st), flush=True)
         return st, reqs, rec

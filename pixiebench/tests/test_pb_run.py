@@ -154,3 +154,95 @@ def test_control_breaking_the_language_bias_fails():
     ok, numbers = check.judge(np.asarray(gaps), 0, cfg["check"])
     assert not ok
     assert numbers["mismatch_share"]["value"] == 1.0
+
+
+RANKER = {"d_model": 32, "n_neighbors": 8, "n_candidates": 64, "final_k": 16,
+          "scenarios": ["related_pins", "homefeed"]}
+
+
+def _ranked_root(tmp_path):
+    """A benchmark tree holding a tiny ranked configuration and a mix that
+    names its head, as files only."""
+    cfg, mix = _tiny()
+    cfg = dict(cfg, name="tiny-ranked", ranker=RANKER)
+    cfg["check"] = dict(cfg["check"], rank_gap_tol=1e-4)
+    for sub in ("configs", "traffic"):
+        (tmp_path / "pixiebench" / sub).mkdir(parents=True)
+    (tmp_path / "pixiebench" / "configs" / "tiny-ranked.json").write_text(
+        json.dumps(cfg))
+    (tmp_path / "pixiebench" / "traffic" / "tiny-ranked.json").write_text(
+        json.dumps(dict(mix, scenario="homefeed")))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        "configs": [{"name": "tiny-ranked",
+                     "file": "pixiebench/configs/tiny-ranked.json"}],
+        "workloads": [{"name": "tiny-ranked", "config": "tiny-ranked",
+                       "traffic": "tiny-ranked", "chips": 1}],
+    }))
+    return tmp_path
+
+
+def test_ranked_configuration_runs_from_data_files(on_cpu, tmp_path):
+    from pixiebench import loadgen
+
+    root = _ranked_root(tmp_path)
+    bench = registry.load_benchmark(root)
+    cell = registry.cell(bench, "tiny-ranked")
+    cfg = registry.config(bench, cell["config"], root)
+    mix = registry.traffic(cell["traffic"], root / "pixiebench")
+    seed = 2**33 + 11
+    c = run.set_up(cell, cfg, mix, seed)
+    assert c.server.ranker.cfg.scenarios == tuple(RANKER["scenarios"])
+    assert c.scenario == 1
+    reqs = loadgen.schedule(mix, seed, 1.0, c.offsets)
+    w = run.measure(c, reqs, False)
+    assert w.compiles == 0
+    for _, ids in w.rec["answers"].values():
+        assert ids.shape == (RANKER["final_k"],)
+    ok, numbers = run.free_and_check(c, w.rec, reqs)
+    assert ok, numbers
+    assert numbers["mismatch_share"]["value"] == 0.0
+    assert numbers["compared_at_least"]["value"] == cfg["check"]["sample"]
+
+
+def test_retrieval_only_cell_builds_and_submits_as_before(on_cpu):
+    from pixiebench import loadgen
+
+    made, sent = [], []
+    init, submit = PixieServer.__init__, PixieServer.submit
+
+    def recording_init(self, *args, **kw):
+        made.append((len(args), sorted(kw)))
+        init(self, *args, **kw)
+
+    def recording_submit(self, *args, **kw):
+        sent.append((len(args), tuple(sorted(kw))))
+        return submit(self, *args, **kw)
+
+    on_cpu.setattr(PixieServer, "__init__", recording_init)
+    on_cpu.setattr(PixieServer, "submit", recording_submit)
+    cfg, mix = _tiny()
+    c = run.set_up({"name": "tiny", "chips": 1}, cfg, mix, 3)
+    assert c.scenario is None and c.server.ranker is None
+    assert made == [(2, ["n_slots", "seed"])]
+    warm = len(sent)
+    reqs = loadgen.schedule(mix, 3, 1.0, c.offsets)
+    run.measure(c, reqs, False)
+    assert set(sent[:warm]) == {(2, ("user_feat",))}
+    assert set(sent[warm:]) == {(2, ("now", "user_feat"))}
+    assert len(sent) - warm == len(reqs)
+
+
+@pytest.mark.parametrize("ranked,scenario,message", [
+    (True, None, "names no scenario"),
+    (False, "homefeed", "states no ranker"),
+    (True, "home_feed", "unknown scenario"),
+])
+def test_configuration_and_mix_that_disagree_are_refused(
+        on_cpu, ranked, scenario, message):
+    cfg, mix = _tiny()
+    if ranked:
+        cfg = dict(cfg, ranker=RANKER)
+    if scenario is not None:
+        mix = dict(mix, scenario=scenario)
+    with pytest.raises(ValueError, match=message):
+        run.set_up({"name": "tiny", "chips": 1}, cfg, mix, 3)

@@ -286,3 +286,50 @@ def test_stages_command_refuses_without_a_tpu(capsys):
     assert rc == 3
     assert "needs a TPU" in out.err
     assert "{" not in out.out
+
+
+def test_measure_fills_run_stages_from_the_trace(monkeypatch):
+    """A traced window of a tiny CPU cell, its profile replaced by the
+    recorded XSpace above: ``run.measure`` reduces it to the stage split
+    with the server's counters over the window, and the readers read it."""
+    import os
+
+    from jax.profiler import ProfileData
+
+    from pixiebench import loadgen, run
+    from pixiebench.tests.test_pb_run import _tiny
+
+    def recorded(log_dir):
+        d = os.path.join(log_dir, "plugins", "profile", "1")
+        os.makedirs(d)
+        with open(os.path.join(d, "t.xplane.pb"), "wb") as f:
+            f.write(ProfileData.text_proto_to_serialized_xspace(XSPACE))
+
+    monkeypatch.setattr(run, "require_tpu",
+                        lambda chips: jax.devices()[:chips])
+    monkeypatch.setattr(run, "peaks_for", lambda kind: {})
+    monkeypatch.setattr(run, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(trace, "start", recorded)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    cfg, mix = _tiny()
+    c = run.set_up({"name": "tiny", "chips": 1}, cfg, mix, 7)
+    reqs = loadgen.schedule(mix, 7, 1.0, c.offsets)
+    w = run.measure(c, reqs, True)
+    assert w.stages.device_ns == {"pixie.walk": 60e3, "pixie.walk.hop": 30e3,
+                                  "pixie.topk": 4e3, stages.UNSCOPED: 6e3}
+    n = len(reqs)
+    assert w.stages.counters["lanes_filled"] == n
+    assert w.stages.counters["lanes_dispatched"] == 8 * w.rec["batches"]
+    assert w.stages.counters["batches_batch_native"] == w.rec["batches"]
+    assert w.stages.counters["steps_budgeted"] == n * cfg["walk"]["n_steps"]
+    r = run.window_run(reqs, w, 1.0, 0.0)
+    assert r.stages is w.stages and r.summary is w.summary
+    hop = registry.metric_reader("walk_hop_ms")(r)
+    assert hop == pytest.approx(30e3 / w.rec["batches"] / 1e6)
+    assert registry.metric_reader("batch_fill")(r) == pytest.approx(
+        100.0 * n / (8 * w.rec["batches"]))
+    # a retrieval-only run has no stage 2; a ranked one reads its scope
+    rank_ms = registry.metric_reader("rank_ms")
+    assert rank_ms(r) is None
+    ranked = stages.Stages({"pixie.rank": 8 * MS}, {}, [], {})
+    assert rank_ms(_run(ranked, batches=4)) == pytest.approx(2.0)
